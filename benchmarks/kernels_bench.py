@@ -43,23 +43,4 @@ def bench_kernels():
     h = jax.jit(lambda x: x + 0)  # copy through XLA
     rows.append(("bulk_copy_8MB_us", round(_time(h, x), 1), "HBM-bound op"))
 
-    # policy-VM batch scoring: 256 packed tables x one [N_LOADS, 64]
-    # queue env — the policy-axis screening hot spot. The jnp reference
-    # is the timed path on CPU; the Pallas kernel is checked for
-    # bit-identity in interpret mode (its perf story is TPU Mosaic).
-    from repro.core import smcprog
-    from repro.kernels.ref import policy_vm_ref
-    from repro.kernels.policy_vm import policy_vm_scores
-    rng = np.random.RandomState(0)
-    from repro.core.policysearch import random_program
-    progs = [random_program(rng, name=f"p{i}") for i in range(256)]
-    tables = jnp.asarray(smcprog.pack_stack(progs, bucket=8))
-    envm = jnp.asarray(rng.randint(0, 1 << 16,
-                                   (smcprog.N_LOADS, 64)), jnp.int32)
-    pv = jax.jit(policy_vm_ref)
-    rows.append(("policy_vm_256x64_ref_us", round(_time(pv, tables, envm), 1),
-                 "jnp path (256 tables)"))
-    ker = policy_vm_scores(tables, envm, interpret=True)
-    ok = bool(jnp.array_equal(ker, pv(tables, envm)))
-    rows.append(("policy_vm_kernel_bitident", ok, "pallas == ref"))
     return rows

@@ -15,11 +15,9 @@ runs one section (e.g. ``sim_speed`` for the engine throughput gate,
 (env fingerprint header + section rows + wall times + compile-cache
 stats) so the perf trajectory is tracked and comparable across PRs and
 environments; ``--quick`` defaults it to ``artifacts/BENCH_quick.json``.
-PR 5 gates (``--quick``): the overlapped campaign executor must beat
+PR 5 gate (``--quick``): the overlapped campaign executor must beat
 the serial group loop >= 1.5x warm (``executor_speed_overlap_speedup_x``,
-multicore hosts), and a second process over the persistent XLA cache
-must skip every recompile (``executor_speed_pcache_second_hits`` > 0,
-``..._misses`` == 0).
+multicore hosts).
 PR 7 gates (``--quick``, section ``streaming``): a 1M-request stream
 through the constant-memory chunked-window driver must finish with
 per-chunk throughput >= 0.9x the 8x4000 single-shot steady state
@@ -50,9 +48,7 @@ policy sweep through the runtime-operand axis must compile once per
 table-length BUCKET, not per program (``policy_axis_compiles`` ==
 ``policy_axis_buckets``), beat the PR-4 staged per-program loop >= 5x
 per policy (``policy_axis_speedup_x``), stay bit-identical to the
-staged path (``policy_axis_bitident`` == 1), and the Pallas policy-VM
-kernel must match its jnp reference (``policy_axis_pallas_bitident``
-== 1). The reference run is ``--section policy_axis --out
+staged path (``policy_axis_bitident`` == 1). The reference run is ``--section policy_axis --out
 artifacts/BENCH_10.json``.
 """
 from __future__ import annotations
@@ -69,8 +65,6 @@ POLICY_ROW = "policy_sweep_interp_overhead_x"
 POLICY_GATE = 1.3  # policy-VM scan must stay within 1.3x of hard-coded
 EXEC_ROW = "executor_speed_overlap_speedup_x"
 EXEC_GATE = 1.5    # overlapped executor vs serial group loop, warm cache
-PCACHE_HITS_ROW = "executor_speed_pcache_second_hits"
-PCACHE_MISSES_ROW = "executor_speed_pcache_second_misses"
 STREAM_RATIO_ROW = "streaming_tput_ratio"
 STREAM_RATIO_GATE = 0.9   # stream vs 8x4000 single-shot steady throughput
 STREAM_KEYS_ROW = "streaming_compile_keys"
@@ -92,17 +86,15 @@ PAXIS_BUCKETS_ROW = "policy_axis_buckets"
 PAXIS_SPEEDUP_ROW = "policy_axis_speedup_x"
 PAXIS_SPEEDUP_GATE = 5.0  # batched axis vs staged per-program loop
 PAXIS_BITIDENT_ROW = "policy_axis_bitident"
-PAXIS_PALLAS_ROW = "policy_axis_pallas_bitident"
 
 
 def _env_header() -> dict:
     """Environment fingerprint for BENCH_<n>.json comparability: the
     same rows mean different things on a different jax/jaxlib, device
-    topology, or scan runtime (see ROADMAP perf note)."""
+    topology (see ROADMAP perf note)."""
     import jax
     import jaxlib
     devs = jax.local_devices()
-    flags = os.environ.get("XLA_FLAGS", "")
     return {
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
@@ -110,7 +102,6 @@ def _env_header() -> dict:
         "device_kind": devs[0].device_kind if devs else "none",
         "platform": devs[0].platform if devs else "none",
         "cpu_count": os.cpu_count(),
-        "fast_cpu_scan": "xla_cpu_use_thunk_runtime=false" in flags,
     }
 
 
@@ -122,11 +113,6 @@ def main() -> None:
                     help="write section rows + wall times + cache stats "
                          "as JSON (BENCH_<n>.json)")
     args = ap.parse_args()
-
-    # must precede the first jax computation: the XLA:CPU thunk runtime
-    # is a 30-40x steady-state slowdown on the emulator scan
-    from repro.utils.jax_compat import enable_fast_cpu_scan
-    enable_fast_cpu_scan()
 
     from benchmarks import kernels_bench, paper, roofline
     from repro.core import emulator
@@ -191,14 +177,12 @@ def main() -> None:
         dt = time.perf_counter() - t0
         for r in rows:
             if r[0] in (STEADY_ROW, POLICY_ROW, EXEC_ROW,
-                        PCACHE_HITS_ROW, PCACHE_MISSES_ROW,
                         STREAM_RATIO_ROW, STREAM_KEYS_ROW, STREAM_RSS_ROW,
                         FAULTS_KEYS_ROW, FAULTS_OFF_ROW, FAULTS_CKPT_ROW,
                         SERVICE_SCALING_ROW, SERVICE_COAL_ROW,
                         SERVICE_REJ_ROW,
                         PAXIS_COMPILES_ROW, PAXIS_BUCKETS_ROW,
-                        PAXIS_SPEEDUP_ROW, PAXIS_BITIDENT_ROW,
-                        PAXIS_PALLAS_ROW):
+                        PAXIS_SPEEDUP_ROW, PAXIS_BITIDENT_ROW):
                 gate_values[r[0]] = float(r[1])
         report["sections"][name] = {
             "rows": [list(r) for r in rows],
@@ -223,9 +207,8 @@ def main() -> None:
         if policy_value is None or policy_value > POLICY_GATE:
             failures += 1
             print(f"_policy_gate,FAIL,{POLICY_ROW}={policy_value}")
-    # executor gates: (a) the overlapped group executor must beat the
-    # serial PR 4 loop warm (only meaningful with >1 hardware thread);
-    # (b) the second persistent-cache process must skip every compile
+    # executor gate: the overlapped group executor must beat the serial
+    # PR 4 loop warm (only meaningful with >1 hardware thread)
     if "executor_speed" in sections \
             and not report["sections"]["executor_speed"]["error"]:
         from repro.core import executor
@@ -236,11 +219,6 @@ def main() -> None:
                 and (exec_value is None or exec_value < EXEC_GATE):
             failures += 1
             print(f"_executor_gate,FAIL,{EXEC_ROW}={exec_value}")
-        hits = gate_values.get(PCACHE_HITS_ROW)
-        misses = gate_values.get(PCACHE_MISSES_ROW)
-        if not hits or misses is None or misses > 0:
-            failures += 1
-            print(f"_pcache_gate,FAIL,hits={hits},misses={misses}")
     # streaming gates: throughput parity with the single-shot steady
     # state, exactly one length-independent compile key, bounded RSS
     if "streaming" in sections \
@@ -307,8 +285,7 @@ def main() -> None:
     # policy-axis gates (ISSUE 10): a 256-candidate sweep must compile
     # once per table-length BUCKET (not per program), beat the staged
     # per-program loop >= 5x per policy, and stay bit-identical to the
-    # staged path — with the Pallas policy-VM kernel matching its
-    # reference on the same tables
+    # staged path
     if "policy_axis" in sections \
             and not report["sections"]["policy_axis"]["error"]:
         compiles = gate_values.get(PAXIS_COMPILES_ROW)
@@ -322,11 +299,10 @@ def main() -> None:
             failures += 1
             print(f"_policy_axis_gate,FAIL,{PAXIS_SPEEDUP_ROW}={speedup}"
                   f"<gate={PAXIS_SPEEDUP_GATE}")
-        for rowname in (PAXIS_BITIDENT_ROW, PAXIS_PALLAS_ROW):
-            if gate_values.get(rowname) != 1:
-                failures += 1
-                print(f"_policy_axis_gate,FAIL,{rowname}="
-                      f"{gate_values.get(rowname)}")
+        if gate_values.get(PAXIS_BITIDENT_ROW) != 1:
+            failures += 1
+            print(f"_policy_axis_gate,FAIL,{PAXIS_BITIDENT_ROW}="
+                  f"{gate_values.get(PAXIS_BITIDENT_ROW)}")
 
     report["cache_stats"] = emulator.cache_stats()
     report["failures"] = failures

@@ -15,13 +15,6 @@ closing autotuning demo (``core.policysearch``) affordable.
 
   PYTHONPATH=src python examples/policy_lab.py
 """
-# before any repro.core import: emulator.py creates a device constant at
-# import time, which initializes the CPU backend and locks the runtime
-# (enable_fast_cpu_scan raises if called too late)
-from repro.utils.jax_compat import enable_fast_cpu_scan
-
-enable_fast_cpu_scan()
-
 import numpy as np
 
 from repro.core import emulator, smcprog

@@ -14,12 +14,6 @@ executable and stay bit-identical to single-shot.
 
   PYTHONPATH=src python examples/quickstart.py
 """
-# before any repro.core import: emulator.py creates a device constant at
-# import time, which initializes the CPU backend and locks the runtime
-from repro.utils.jax_compat import enable_fast_cpu_scan
-
-enable_fast_cpu_scan()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

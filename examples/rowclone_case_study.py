@@ -4,8 +4,9 @@ faithfully model a modern CPU inflate DRAM-technique benefits.
 
   PYTHONPATH=src python examples/rowclone_case_study.py
 
-Second runs start fast: XLA executables persist in artifacts/xla_cache
-(enable_persistent_compile_cache below), so a fresh process skips the
+Second runs start fast: XLA executables persist in
+``$JAX_COMPILATION_CACHE_DIR`` (else ``<checkout>/artifacts/xla_cache``;
+enable_persistent_compile_cache below), so a fresh process skips the
 cold compiles, and the size sweeps execute through the overlapped
 campaign executor.
 """
@@ -13,11 +14,9 @@ import warnings
 
 warnings.filterwarnings("ignore")
 
-# both must precede the first jax computation (backend init)
-from repro.utils.jax_compat import (enable_fast_cpu_scan,
-                                    enable_persistent_compile_cache)
+# before the first compile: jax latches the cache decision there
+from repro.utils.jax_compat import enable_persistent_compile_cache
 
-enable_fast_cpu_scan()
 enable_persistent_compile_cache()
 
 from repro.core.dram import Geometry

@@ -10,12 +10,6 @@ import warnings
 
 warnings.filterwarnings("ignore")
 
-# before any repro.core import: emulator.py creates a device constant at
-# import time, which initializes the CPU backend and locks the runtime
-from repro.utils.jax_compat import enable_fast_cpu_scan
-
-enable_fast_cpu_scan()
-
 import jax
 import numpy as np
 

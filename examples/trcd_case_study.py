@@ -3,8 +3,9 @@ build the weak-row Bloom filter, run PolyBench-like workloads end-to-end.
 
   PYTHONPATH=src python examples/trcd_case_study.py
 
-Second runs start fast: XLA executables persist in artifacts/xla_cache
-(enable_persistent_compile_cache below), so a fresh process skips the
+Second runs start fast: XLA executables persist in
+``$JAX_COMPILATION_CACHE_DIR`` (else ``<checkout>/artifacts/xla_cache``;
+enable_persistent_compile_cache below), so a fresh process skips the
 cold compiles; the base + reduced arms of the whole kernel suite then
 run through the overlapped campaign executor.
 """
@@ -12,11 +13,9 @@ import warnings
 
 warnings.filterwarnings("ignore")
 
-# both must precede the first jax computation (backend init)
-from repro.utils.jax_compat import (enable_fast_cpu_scan,
-                                    enable_persistent_compile_cache)
+# before the first compile: jax latches the cache decision there
+from repro.utils.jax_compat import enable_persistent_compile_cache
 
-enable_fast_cpu_scan()
 enable_persistent_compile_cache()
 
 import numpy as np

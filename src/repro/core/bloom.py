@@ -3,8 +3,8 @@
 Host-built (numpy) from the characterization pass, probed inside the
 software memory controller on every row activation. Keys are weak rows,
 so a false positive only means a weak-timing row gets *nominal* tRCD —
-never an unsafe reduced access. The JAX probe here is the reference; the
-Pallas kernel in ``repro.kernels.bloom_probe`` is the TPU-optimized twin.
+never an unsafe reduced access. The engine probes with
+:func:`bloom_probe_jnp` inside its scan.
 """
 from __future__ import annotations
 

@@ -99,12 +99,9 @@ Entry points:
   bit-identical to single-shot :func:`run` on any size both support
   (see the freeze-rule note on :func:`_stream_step_core`).
 
-Note on XLA:CPU: the thunk runtime (jaxlib >= 0.4.32 default) executes
-the tiny per-slot ops of this scan through its intra-op thread pool and
-defeats in-place carry updates — a ~30x steady-state slowdown. Benchmark
-and example entry points call
-:func:`repro.utils.jax_compat.enable_fast_cpu_scan` before the backend
-initializes to select the legacy inline runtime.
+Importing this module does no device work (every module-level constant
+is a host value), so a process can pick its backend and compile-cache
+settings after the import.
 """
 from __future__ import annotations
 
@@ -124,7 +121,7 @@ from repro.core.bloom import bloom_probe_jnp
 from repro.core.dram import NOP, WRITE
 from repro.core.timescale import SystemConfig
 
-BIG = jnp.int32(2 ** 30)
+BIG = np.int32(2 ** 30)  # host constant: importing touches no device
 FP = 4096  # fixed-point denominator for tick<->cycle conversion
 # issue-frontier advances per scheduling slot; the streaming freeze rule
 # and halo sizing are derived from it, so it is a named constant
@@ -1019,7 +1016,9 @@ def _shard_wrap(fn, nshards: int, bshape, pshape=None):
     policy tables/costs (the runtime policy axis) shard. Inside
     each shard the wrapped fn sees a ``batch/nshards`` slice and vmaps
     over it exactly as in the unsharded path, so results concatenate to
-    the bit-identical full batch."""
+    the bit-identical full batch. The body is a pure per-shard vmap
+    with no collectives, so the varying-axis check is off: the scan's
+    initial carry is built inside the body and is not batch-varying."""
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.utils import jax_compat
@@ -1032,8 +1031,7 @@ def _shard_wrap(fn, nshards: int, bshape, pshape=None):
     if pshape is not None:
         in_specs = in_specs + (spec, spec)
     return jax_compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=spec,
-                                **jax_compat.shard_map_kwargs())
+                                out_specs=spec, check_vma=False)
 
 
 class _CachedRunner:
@@ -1044,15 +1042,10 @@ class _CachedRunner:
     all-zeros dummy batch (all-NOP-free zero reads; one scan execution,
     noise next to the compile). ``prepare_tasks`` primes every resolved
     runner in group order on the caller's thread before any executor
-    worker starts, which buys two properties the lazy first-call would
-    lose: (a) tracing/lowering interleaved across worker threads makes
-    jax's uid counters — and so the emitted StableHLO bytes and the
-    persistent on-disk cache key — nondeterministic across processes
-    (observed: one fresh disk entry per run); (b) only the *warmed* C++
-    jit fast path executes synchronously on the calling thread under
-    the inline CPU runtime — an unwarmed call (and the AOT
-    ``Lowered.compile()(...)`` path) enqueues onto the device's single
-    execute thread, which silently serializes the overlapped groups."""
+    worker starts: tracing/lowering interleaved across worker threads
+    makes jax's uid counters — and so the emitted StableHLO bytes and
+    the persistent on-disk cache key — nondeterministic across
+    processes (observed: one fresh disk entry per run)."""
 
     __slots__ = ("jitted", "avals", "primed")
 

@@ -1,16 +1,14 @@
 """Overlapped campaign executor: run prepared compile-key groups with
 host/device overlap instead of the serial pack -> dispatch -> block loop.
 
-Why a thread pool and not async dispatch: on XLA:CPU under the inline
-runtime (``jax_compat.enable_fast_cpu_scan``) an executable runs
-synchronously on the calling thread, so ``fn(*args)`` only returns after
-the scan finishes — there is nothing to overlap from one Python thread.
-XLA does release the GIL for the whole execution, though, so two
-*threads* genuinely overlap: while a worker is inside XLA running group
-k, another worker packs (``np.stack`` / padding, pure Python+NumPy) and
-then executes group k+1 on the second core. Measured on the emulator
-scan this is ~1.6-1.9x over the serial loop on a 2-core host, scaling
-with cores until group compute is exhausted.
+Why a thread pool and not async dispatch alone: every task ends by
+gathering its outputs to the host (``np.asarray``), which blocks its
+thread until the device finishes, so one Python thread would serialize
+pack -> dispatch -> gather. XLA releases the GIL while it runs, so two
+*threads* overlap: while a worker waits on group k, another packs
+(``np.stack`` / padding, pure Python+NumPy) and dispatches group k+1.
+Whether that overlap pays on a given device is a measurement, not a
+property of this module.
 
 Determinism contract:
 

@@ -114,18 +114,16 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-# Same sentinel value as repro.core.emulator.BIG — but a plain Python
-# int: a module-level jnp constant would initialize the JAX backend at
-# import time, and this module is imported by the otherwise jax-free
-# config layer (timescale.py), which must stay importable before
-# jax_compat.enable_fast_cpu_scan().
+# Same sentinel value as repro.core.emulator.BIG, as a host value: a
+# module-level jnp constant would initialize the JAX backend at import
+# time, before the process has picked its backend and compile cache.
 BIG = 2 ** 30
 
 # ---------------------------------------------------------------------------
@@ -573,19 +571,6 @@ def pack_program(prog: PolicyProgram,
     return out
 
 
-def pack_stack(progs: Sequence[PolicyProgram],
-               bucket: Optional[int] = None) -> np.ndarray:
-    """Stack packed programs into one ``[P, bucket + 1, 4]`` int32 array
-    — the policy-axis operand. ``bucket`` defaults to the max bucket
-    over the programs (callers that must NOT silently merge buckets,
-    e.g. ``Campaign.add_policy_grid``, group first and pass it)."""
-    if not progs:
-        raise ValueError("pack_stack needs at least one program")
-    lb = (max(table_bucket(p.n_ops) for p in progs)
-          if bucket is None else int(bucket))
-    return np.stack([pack_program(p, lb) for p in progs])
-
-
 def eval_table_rows(rows, envm):
     """The table-driven VM core: interpret ``rows`` ([L, 4] int32
     instructions) over ``envm`` ([N_LOADS, Q] int32 stacked environment)
@@ -594,9 +579,8 @@ def eval_table_rows(rows, envm):
     traces to a fixed dataflow program regardless of table content.
     Candidate arithmetic matches :func:`evaluate` op for op (int32
     wraparound included), which is what makes the runtime path
-    bit-identical to the staged path. Shared verbatim by
-    :func:`evaluate_table` and the ``kernels/policy_vm`` Pallas kernel
-    (single source of semantics)."""
+    bit-identical to the staged path. :func:`evaluate_table` is its
+    one caller (single source of semantics)."""
     L = rows.shape[0]
     q = envm.shape[1]
 

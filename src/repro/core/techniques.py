@@ -313,17 +313,22 @@ class TRCDReduction:
         """Run a workload with and without reduced-tRCD scheduling."""
         return self.evaluate_traces([trace], mode_ts)[0]
 
-    def evaluate_traces(self, trs: Sequence, mode_ts: str = "ts") -> List[dict]:
-        """Batched base-vs-reduced sweep: every trace is evaluated with
-        and without the Bloom filter through one Campaign (one compile
-        per (bucket, bloom-presence) group). Returns per-trace dicts in
-        input order."""
+    def campaign(self, trs: Sequence, mode_ts: str = "ts") -> Campaign:
+        """The base-vs-reduced grid: every trace with and without the
+        Bloom filter (records carry ``i`` = trace index and ``arm``)."""
         bloom = self.bloom_tuple
         c = Campaign()
         for i, tr in enumerate(trs):
             c.add(tr, self.sys, mode=mode_ts, i=i, arm="base")
             c.add(tr, self.sys, mode=mode_ts, bloom=bloom, i=i, arm="reduced")
-        arms = {(r["i"], r["arm"]): int(r["exec_cycles"]) for r in c.run()}
+        return c
+
+    def evaluate_traces(self, trs: Sequence, mode_ts: str = "ts") -> List[dict]:
+        """Batched base-vs-reduced sweep: :meth:`campaign` in one run
+        (one compile per (bucket, bloom-presence) group). Returns
+        per-trace dicts in input order."""
+        arms = {(r["i"], r["arm"]): int(r["exec_cycles"])
+                for r in self.campaign(trs, mode_ts).run()}
         return [{
             "base_cycles": arms[(i, "base")],
             "reduced_cycles": arms[(i, "reduced")],

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import gzip
+import zlib
 from typing import Iterator, Optional
 
 import numpy as np
@@ -456,7 +457,9 @@ POLYBENCH = (
 
 def polybench_stream(kern: Kernel, max_accesses=60000, seed=0):
     """CPU-level address stream for a kernel: interleaved strided passes."""
-    rng = np.random.RandomState(seed + hash(kern.name) % 1000)
+    # crc32, not hash(): str hashes are salted per process, and the
+    # trace must be the same in every process for a given seed
+    rng = np.random.RandomState(seed + zlib.crc32(kern.name.encode()) % 1000)
     streams = []
     base = 0
     for (nb, stride, passes) in kern.arrays:

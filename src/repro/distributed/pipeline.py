@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.jax_compat import pvary, shard_map, shard_map_kwargs
+from repro.utils.jax_compat import pvary, shard_map
 
 
 def pipeline_apply(params_stacked, x_mb, stage_fn, mesh, axis: str = "pod"):
@@ -28,8 +28,7 @@ def pipeline_apply(params_stacked, x_mb, stage_fn, mesh, axis: str = "pod"):
     pspec_params = jax.tree_util.tree_map(lambda _: P(axis), params_stacked)
 
     @partial(shard_map, mesh=mesh,
-             in_specs=(pspec_params, P()), out_specs=P(),
-             **shard_map_kwargs())
+             in_specs=(pspec_params, P()), out_specs=P())
     def run(params_local, x_all):
         # params_local leaves: [1, ...] — this device's stage
         p = jax.tree_util.tree_map(lambda a: a[0], params_local)

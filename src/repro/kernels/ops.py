@@ -8,23 +8,13 @@ long-context fallback to the chunked-XLA path.
 """
 from __future__ import annotations
 
-import os
-
 import jax
-import jax.numpy as jnp
 
-from repro.kernels import bloom_probe as _bp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rowclone_copy as _rc
 
 _INTERPRET = jax.default_backend() == "cpu"
 _MAX_KV_VMEM = 8192  # Sk beyond this falls back to the chunked XLA path
-
-# REPRO_POLICY_VM_KERNEL: "1" forces the Pallas policy-VM kernel (in
-# interpret mode on CPU), "0" forces the pure-jnp reference. Default:
-# kernel on accelerators, reference on CPU (interpret-mode tracing is a
-# correctness tool, not a fast path).
-_POLICY_VM_FLAG = os.environ.get("REPRO_POLICY_VM_KERNEL", "")
 
 
 def flash_attention(q, k, v, causal=True):
@@ -46,25 +36,6 @@ def flash_attention(q, k, v, causal=True):
             .transpose(0, 2, 1, 3))
 
 
-def bloom_probe(words, keys, k: int, m_bits: int):
-    return _bp.bloom_probe(jnp.asarray(words), jnp.asarray(keys, jnp.uint32),
-                           k=k, m_bits=m_bits, interpret=_INTERPRET)
-
-
 def rowclone_copy(x):
     return _rc.rowclone_copy(x, interpret=_INTERPRET)
 
-
-def policy_vm(tables, envm):
-    """Batch policy-VM scoring: packed tables [P, L+1, 4] x shared env
-    [N_LOADS, Q] -> [P, 3, Q] (score, boost, mitigate). Routes to the
-    Pallas kernel or the jnp reference per ``REPRO_POLICY_VM_KERNEL``
-    (see module docstring); both are bit-identical by construction —
-    they share ``smcprog.eval_table_rows``."""
-    use_kernel = (_POLICY_VM_FLAG == "1"
-                  or (_POLICY_VM_FLAG != "0" and not _INTERPRET))
-    if use_kernel:
-        from repro.kernels import policy_vm as _pv
-        return _pv.policy_vm_scores(tables, envm, interpret=_INTERPRET)
-    from repro.kernels import ref as _ref
-    return _ref.policy_vm_ref(tables, envm)

@@ -4,8 +4,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.bloom import bloom_probe_jnp
-
 
 def flash_attention_ref(q, k, v, causal=True):
     """q: [BHq, Sq, hd]; k, v: [BHkv, Sk, hd] (GQA by ratio)."""
@@ -23,34 +21,6 @@ def flash_attention_ref(q, k, v, causal=True):
     return jnp.einsum("bqk,bkd->bqd", p, vf).astype(q.dtype)
 
 
-def bloom_probe_ref(words, keys, k: int, m_bits: int):
-    return bloom_probe_jnp(jnp.asarray(words), m_bits, k,
-                           keys).astype(jnp.int8)
-
-
 def rowclone_copy_ref(x):
     return x
 
-
-def policy_vm_ref(tables, envm):
-    """Pure-jnp oracle for ``policy_vm_scores``: vmap of the table VM
-    over the program axis. tables [P, L+1, 4], envm [N_LOADS, Q] ->
-    [P, 3, Q] int32 (score, boost, mitigate)."""
-    from repro.core.smcprog import eval_table_rows
-    tables = jnp.asarray(tables, jnp.int32)
-    envm = jnp.asarray(envm, jnp.int32)
-
-    def one(table):
-        hdr = table[0]
-        rows = table[1:]
-        lb = rows.shape[0]
-        vals = eval_table_rows(rows, envm)
-        score = vals[jnp.clip(hdr[1], 0, lb - 1)]
-        zero = jnp.zeros_like(score)
-        boost = jnp.where(hdr[2] >= 0,
-                          vals[jnp.clip(hdr[2], 0, lb - 1)], zero)
-        mit = jnp.where(hdr[3] >= 0,
-                        vals[jnp.clip(hdr[3], 0, lb - 1)], zero)
-        return jnp.stack([score, boost, mit])
-
-    return jax.vmap(one)(tables)

@@ -1,127 +1,42 @@
-"""Version-compat shims over the installed JAX.
+"""One place for the JAX API surface the codebase leans on.
 
-The codebase targets the shard_map/cost_analysis API surface of recent
-JAX, but must run on whatever the container ships (currently 0.4.37).
-Every call site goes through these helpers instead of probing
-``jax.<attr>`` itself, so a JAX upgrade changes exactly one file.
+Every ``shard_map`` / ``cost_analysis`` / persistent-cache call site goes
+through these helpers instead of probing ``jax.<attr>`` itself, so a JAX
+upgrade changes exactly one file.
 
-* :data:`shard_map` — ``jax.shard_map`` when present (>= 0.6), else
-  ``jax.experimental.shard_map.shard_map``.
-* :func:`pvary` — mark a value device-varying over mesh axes. Newer
-  shard_map requires the annotation (``jax.lax.pvary`` /
-  ``jax.lax.pcast``); older shard_map has no such notion, so the shim
-  degrades to identity (pair with ``shard_map_kwargs`` below, which
-  disables replication checking there).
-* :func:`shard_map_kwargs` — extra kwargs for :data:`shard_map` on this
-  JAX version (``check_rep=False`` on old JAX, where device-varying
-  carries would otherwise fail the replication checker).
-* :func:`cost_analysis_dict` — ``Compiled.cost_analysis()`` normalized
-  to one flat dict. Depending on version it returns a dict, a list with
-  one dict per partition, or None.
-* :func:`enable_fast_cpu_scan` — select the XLA:CPU runtime that keeps
-  the emulator's long scalar-carry scans fast (see docstring). Call it
-  at process entry, before the first jax computation; calling it after
-  the backend initialized raises (the flag would be silently ignored).
+* :data:`shard_map` — ``jax.shard_map``.
+* :func:`pvary` — mark a value device-varying over mesh axes inside
+  ``shard_map`` (``jax.lax.pcast(..., to="varying")``).
+* :func:`cost_analysis_dict` — ``Compiled.cost_analysis()`` as one flat
+  dict (``{}`` where the backend reports nothing).
 * :func:`enable_persistent_compile_cache` — wire up JAX's on-disk XLA
-  compilation cache (default ``artifacts/xla_cache/``) so a fresh
-  process re-running an already-seen sweep skips the cold compiles;
-  :func:`persistent_cache_stats` counts its hits/misses via the JAX
-  monitoring events (version-tolerant: counters stay zero if the event
-  API moved).
+  compilation cache so a fresh process re-running an already-seen sweep
+  skips the cold compiles. The directory is ``JAX_COMPILATION_CACHE_DIR``
+  when that is set, else ``<checkout>/artifacts/xla_cache``
+  (:func:`default_cache_dir`); :func:`persistent_cache_stats` counts its
+  hits and misses through JAX's monitoring events.
 """
 from __future__ import annotations
 
 import os
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-    _NEW_SHARD_MAP = True
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-    _NEW_SHARD_MAP = False
+shard_map = jax.shard_map
 
-
-def shard_map_kwargs() -> Dict[str, Any]:
-    """Extra kwargs to pass to :data:`shard_map` on this JAX version."""
-    return {} if _NEW_SHARD_MAP else {"check_rep": False}
+# <checkout>/src/repro/utils/jax_compat.py -> <checkout>
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def pvary(x, axis_names):
     """Mark ``x`` device-varying over ``axis_names`` inside shard_map."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_names, to="varying")
-    return x  # old shard_map: no varying-ness tracking (check_rep=False)
-
-
-def enable_fast_cpu_scan() -> bool:
-    """Select the XLA:CPU runtime that keeps long scalar-carry scans fast.
-
-    The thunk runtime (jaxlib >= 0.4.32 default) executes each of the
-    ~100 tiny ops in the emulator's scan body through its intra-op
-    thread pool and defeats in-place dynamic-update-slice on the scan
-    carry; for an 8k-slot emulation that is ~30 us of synchronization
-    per slot — a 30-40x steady-state slowdown on the batched engine
-    (measured in ``benchmarks/run.py --section sim_speed``). The legacy
-    inline runtime has neither problem. Matmul-heavy model code is
-    unaffected either way (both dispatch to Eigen).
-
-    Also disables XLA:CPU *async dispatch* (where supported): async
-    dispatch enqueues every execution onto one per-device execute
-    thread, which silently serializes the overlapped campaign executor
-    (``repro.core.executor``) — with it off, a warm executable runs
-    synchronously on the calling worker thread, so independent compile
-    groups genuinely execute in parallel across cores.
-
-    Must run before the CPU backend is created: returns True when the
-    flag is (now) in effect for future compilations, and raises
-    ``RuntimeError`` when the backend already initialized without it —
-    the flag would be silently ignored and every emulation scan would
-    quietly run ~30x slower, so a late call is a programming error (fix
-    the call order), not a condition to limp past. Returns False only
-    when the operator explicitly pinned the thunk runtime on via
-    ``XLA_FLAGS`` (their call; warn and respect it). Known caveat: the
-    legacy runtime does not populate per-op ``cost_analysis()``
-    metrics, so flops-accounting tools (``repro.launch.dryrun``)
-    should not run under it.
-    """
-    try:  # sync dispatch: see docstring (anytime config, not an XLA flag)
-        jax.config.update("jax_cpu_enable_async_dispatch", False)
-    except (AttributeError, KeyError):  # pragma: no cover - option absent
-        pass
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" in flags:
-        if "xla_cpu_use_thunk_runtime=false" in flags:
-            return True  # operator already pinned the fast runtime
-        import warnings
-        warnings.warn(
-            "XLA_FLAGS pins xla_cpu_use_thunk_runtime on — emulation "
-            "scans will run ~30x slower steady-state", stacklevel=2)
-        return False
-    try:
-        from jax._src import xla_bridge
-        backend_up = bool(xla_bridge._backends)
-    except (ImportError, AttributeError):  # pragma: no cover - API moved
-        backend_up = False
-    if backend_up:  # flag would be silently ignored — refuse loudly
-        raise RuntimeError(
-            "enable_fast_cpu_scan() called after the JAX backend "
-            "initialized (e.g. after importing repro.core.emulator or "
-            "running any jax computation) — the XLA_FLAGS it sets would "
-            "be ignored and emulation scans would run on the slow thunk "
-            "runtime. Call it first thing at process entry, before any "
-            "repro.core import.")
-    os.environ["XLA_FLAGS"] = \
-        (flags + " --xla_cpu_use_thunk_runtime=false").strip()
-    return True
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
 _PCACHE_STATS = {"hits": 0, "misses": 0}
-_PCACHE_DIR: str | None = None
+_PCACHE_DIR: Optional[str] = None
 
 
 def _pcache_event(event: str, **kwargs) -> None:
@@ -131,9 +46,22 @@ def _pcache_event(event: str, **kwargs) -> None:
         _PCACHE_STATS["misses"] += 1
 
 
-def enable_persistent_compile_cache(
-        cache_dir: str = os.path.join("artifacts", "xla_cache")) -> str:
-    """Persist XLA executables to ``cache_dir`` across processes.
+def default_cache_dir() -> str:
+    """Where :func:`enable_persistent_compile_cache` keeps executables
+    when the caller names no directory: ``JAX_COMPILATION_CACHE_DIR``
+    when set, else ``artifacts/xla_cache`` under the checkout. The
+    fallback is anchored at this file, not the working directory, so
+    every process of one checkout shares one cache (the directory is
+    part of the cache key: a path that moves never hits)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return os.path.abspath(env)
+    return os.path.join(_CHECKOUT, "artifacts", "xla_cache")
+
+
+def enable_persistent_compile_cache(cache_dir: Optional[str] = None) -> str:
+    """Persist XLA executables to ``cache_dir`` across processes
+    (default :func:`default_cache_dir`).
 
     A second process running the same sweep (same shapes, configs, XLA
     flags) then loads each executable from disk instead of re-paying
@@ -141,29 +69,21 @@ def enable_persistent_compile_cache(
     compile-key group. Every entry-size / compile-time threshold is
     zeroed so the emulator's scan executables always qualify.
 
-    Call it at process entry, next to :func:`enable_fast_cpu_scan`:
-    JAX latches its cache-enabled decision at the first compilation, so
-    the defensive ``reset_cache()`` below only reliably re-opens the
-    decision on JAX versions that expose it. Safe to call repeatedly
-    (e.g. to move the directory). Returns the absolute cache dir.
-    """
+    Call it at process entry: JAX latches its cache-enabled decision at
+    the first compilation, and the ``reset_cache()`` below re-opens it.
+    Safe to call repeatedly (e.g. to move the directory). Returns the
+    absolute cache dir."""
     global _PCACHE_DIR
-    cache_dir = os.path.abspath(cache_dir)
+    cache_dir = os.path.abspath(cache_dir or default_cache_dir())
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if _PCACHE_DIR is None:  # register the hit/miss listener once
-        try:
-            from jax._src import monitoring
-            monitoring.register_event_listener(_pcache_event)
-        except (ImportError, AttributeError):  # pragma: no cover
-            pass  # counters stay zero; caching itself still works
-    try:  # re-open JAX's latched is-cache-used decision if already taken
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-    except (ImportError, AttributeError):  # pragma: no cover
-        pass
+        from jax._src import monitoring
+        monitoring.register_event_listener(_pcache_event)
+    from jax._src import compilation_cache
+    compilation_cache.reset_cache()
     _PCACHE_DIR = cache_dir
     return cache_dir
 
@@ -177,20 +97,5 @@ def persistent_cache_stats() -> Dict[str, Any]:
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as one flat {metric: value} dict.
-
-    Newer JAX returns a single dict; 0.4.x returns a list with one dict
-    per partition (sum them — per-device metrics over an SPMD program);
-    some backends return None.
-    """
-    cost = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, dict):
-        return dict(cost)
-    out: Dict[str, float] = {}
-    for part in cost:
-        for k, v in part.items():
-            if isinstance(v, (int, float)):
-                out[k] = out.get(k, 0.0) + v
-    return out
+    """``compiled.cost_analysis()`` as one flat {metric: value} dict."""
+    return dict(compiled.cost_analysis() or {})

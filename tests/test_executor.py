@@ -498,6 +498,50 @@ class TestSharding:
         assert second["pcache"]["misses"] == 0
 
 
+class TestCachePlacement:
+    def test_env_dir_is_the_only_cache_dir(self, tmp_path):
+        """With JAX_COMPILATION_CACHE_DIR set, the persistent cache goes
+        there: enable_persistent_compile_cache sets no other directory,
+        and a run's executables land in it."""
+        env_dir = tmp_path / "env_cache"
+        code = (
+            "import os\n"
+            "import numpy as np\n"
+            "from repro.utils import jax_compat\n"
+            "d = jax_compat.enable_persistent_compile_cache()\n"
+            "import jax\n"
+            "assert d == os.environ['JAX_COMPILATION_CACHE_DIR'], d\n"
+            "assert jax.config.jax_compilation_cache_dir == d\n"
+            "from repro.core.emulator import Trace, run\n"
+            "from repro.core.timescale import JETSON_NANO\n"
+            "run(Trace.of(np.zeros(8), np.arange(8), np.zeros(8),\n"
+            "             np.ones(8)), JETSON_NANO)\n"
+            "assert jax_compat.persistent_cache_stats()['dir'] == d\n")
+        src = os.path.abspath(
+            os.path.join(os.path.dirname(__file__), "..", "src"))
+        env = dict(os.environ, PYTHONPATH=src,
+                   JAX_COMPILATION_CACHE_DIR=str(env_dir))
+        p = subprocess.run([sys.executable, "-c", code], env=env,
+                           cwd=str(tmp_path), capture_output=True,
+                           text=True, timeout=300)
+        assert p.returncode == 0, p.stderr[-2000:]
+        assert os.listdir(env_dir)
+        assert not (tmp_path / "artifacts").exists()
+
+    def test_default_dir_is_the_checkout_not_the_cwd(self, tmp_path,
+                                                     monkeypatch):
+        from repro.utils import jax_compat
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        seen = []
+        for sub in ("a", "b"):
+            (tmp_path / sub).mkdir()
+            monkeypatch.chdir(tmp_path / sub)
+            seen.append(jax_compat.default_cache_dir())
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert seen[0] == seen[1] == os.path.join(root, "artifacts",
+                                                  "xla_cache")
+
+
 class TestCacheLRU:
     def test_lru_bounds_hundred_group_sweep(self):
         """A 100-group sweep must not retain 100 executables: the LRU

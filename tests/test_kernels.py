@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.bloom import BloomFilter
 from repro.kernels import ops, ref
 
 
@@ -32,19 +31,6 @@ def test_flash_attention_sweep(B, S, H, KV, hd, dtype, causal):
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(oref, np.float32), atol=tol, rtol=tol)
-
-
-@pytest.mark.parametrize("m_bits,k,n", [(1 << 14, 2, 100), (1 << 16, 4, 5000),
-                                        (1 << 18, 6, 20000)])
-def test_bloom_probe_sweep(m_bits, k, n):
-    keys_in = np.arange(0, n * 3, 3, dtype=np.uint32)
-    bf = BloomFilter.build(keys_in, m_bits=m_bits, k=k)
-    probes = np.arange(0, n * 4, dtype=np.uint32)
-    got = np.asarray(ops.bloom_probe(bf.bits, probes, k=k, m_bits=m_bits))
-    want = np.asarray(ref.bloom_probe_ref(bf.bits, jnp.asarray(probes), k, m_bits))
-    np.testing.assert_array_equal(got, want)
-    # zero false negatives on inserted keys
-    assert np.asarray(ops.bloom_probe(bf.bits, keys_in, k=k, m_bits=m_bits)).all()
 
 
 @pytest.mark.parametrize("shape", [(8, 128), (64, 512), (33, 257), (1, 8192)])

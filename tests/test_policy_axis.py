@@ -238,19 +238,6 @@ class TestPackingAndVM:
         with pytest.raises(ValueError, match=r"row 1 \(op_add\)"):
             bad.validate()
 
-    def test_pallas_kernel_matches_reference(self):
-        from repro.kernels.policy_vm import policy_vm_scores
-        from repro.kernels.ref import policy_vm_ref
-        rng = np.random.RandomState(3)
-        progs = program_pool(n_random=6) \
-            + list(smcprog.mitigation_programs().values())
-        tables = smcprog.pack_stack(progs, bucket=8)
-        envm = rng.randint(-5, 1 << 16,
-                           (smcprog.N_LOADS, 32)).astype(np.int32)
-        ref = np.asarray(policy_vm_ref(tables, envm))
-        ker = np.asarray(policy_vm_scores(tables, envm, interpret=True))
-        np.testing.assert_array_equal(ref, ker)
-
 
 class TestPolicySearch:
     def test_generators_always_valid(self):
