@@ -59,7 +59,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from repro.core import emulator, executor
+from repro.core import emulator, executor, spans
 from repro.core.emulator import Trace
 from repro.core.smcprog import PolicyProgram
 from repro.core.timescale import SystemConfig
@@ -184,7 +184,7 @@ class Campaign:
     ``add`` order is preserved in ``run()``'s output; extra keyword
     arguments to ``add`` (workload name, technique label, size, ...)
     come back verbatim on each record, which is what makes the output
-    tidy-data-friendly for the paper-figure benchmarks.
+    tidy-data-friendly for the paper-figure scripts.
 
     ``run(checkpoint=dir)`` persists each completed group's results
     incrementally and resumes a killed sweep with zero recomputation;
@@ -302,7 +302,7 @@ class Campaign:
         host-side padding/packing of group k+1 proceeds while group k
         is inside XLA, and independent groups execute concurrently
         across cores. ``serial=True`` keeps the original in-order
-        group loop for A/B (``benchmarks --section executor_speed``);
+        group loop for A/B;
         the default (None) also falls back to it for single-group
         campaigns or a 1-worker pool. Results are bit-identical either
         way, in ``add`` order: the emulator output dict plus the
@@ -344,93 +344,97 @@ class Campaign:
         if on_error not in ("raise", "quarantine"):
             raise ValueError(
                 f"on_error must be 'raise' or 'quarantine', got {on_error!r}")
-        groups: Dict[tuple, List[int]] = {}
-        for i, p in enumerate(self.points):
-            groups.setdefault(p.group_key(), []).append(i)
-        if checkpoint is not None:
-            os.makedirs(checkpoint, exist_ok=True)
+        with spans.span("emu.call") as sp:
+            groups: Dict[tuple, List[int]] = {}
+            for i, p in enumerate(self.points):
+                groups.setdefault(p.group_key(), []).append(i)
+            if checkpoint is not None:
+                os.makedirs(checkpoint, exist_ok=True)
 
-        results: List[Optional[dict]] = [None] * len(self.points)
-        tasks: List[Any] = []
-        merges = []  # (campaign indices, points, group result list, tasks)
-        loaded = 0
-        for key, idxs in groups.items():
-            pts = [self.points[i] for i in idxs]
-            p0 = pts[0]
-            ckpt_path = None
-            if checkpoint is not None and not p0.stream:
-                ckpt_path = os.path.join(
-                    checkpoint, f"group-{_group_digest(key, pts)}.pkl")
-                if os.path.exists(ckpt_path):
-                    with open(ckpt_path, "rb") as fh:
-                        outs = pickle.load(fh)
-                    if len(outs) == len(pts) and all(
-                            o is not None for o in outs):
-                        loaded += 1
-                        merges.append((idxs, pts, outs, []))
-                        continue  # finished group: zero recompute
-            blooms = None
-            if p0.bloom is not None:
-                # one shared filter broadcasts; distinct ones stack
-                same = all(b.bloom is p0.bloom for b in pts)
-                blooms = p0.bloom if same else [p.bloom for p in pts]
-            outs = [None] * len(pts)
-            if p0.stream:
-                gtasks = emulator.prepare_stream_tasks(
-                    [p.trace for p in pts], p0.sys, [p.mode for p in pts],
-                    blooms, outs,
-                    chunk=p0.chunk or emulator.DEFAULT_STREAM_CHUNK,
-                    collect=stream_collect)
-            else:
-                # policy groups never mix with staged/legacy points
-                # (their group_key carries a fifth, policy element)
-                pkw = {} if p0.policy is None else dict(
-                    policies=[p.policy for p in pts],
-                    policy_costs=[p.policy_cost for p in pts])
-                gtasks = emulator.prepare_tasks(
-                    [p.trace for p in pts], p0.sys, [p.mode for p in pts],
-                    blooms, outs, **pkw)
-            if ckpt_path is not None:
-                for gt in gtasks:
-                    gt.finalize = _checkpointed(gt.finalize, outs, ckpt_path)
-            tasks += gtasks
-            merges.append((idxs, pts, outs, gtasks))
+            results: List[Optional[dict]] = [None] * len(self.points)
+            tasks: List[Any] = []
+            merges = []  # (campaign indices, points, group result list, tasks)
+            loaded = 0
+            for key, idxs in groups.items():
+                pts = [self.points[i] for i in idxs]
+                p0 = pts[0]
+                ckpt_path = None
+                if checkpoint is not None and not p0.stream:
+                    ckpt_path = os.path.join(
+                        checkpoint, f"group-{_group_digest(key, pts)}.pkl")
+                    if os.path.exists(ckpt_path):
+                        with open(ckpt_path, "rb") as fh:
+                            outs = pickle.load(fh)
+                        if len(outs) == len(pts) and all(
+                                o is not None for o in outs):
+                            loaded += 1
+                            merges.append((idxs, pts, outs, []))
+                            continue  # finished group: zero recompute
+                blooms = None
+                if p0.bloom is not None:
+                    # one shared filter broadcasts; distinct ones stack
+                    same = all(b.bloom is p0.bloom for b in pts)
+                    blooms = p0.bloom if same else [p.bloom for p in pts]
+                outs = [None] * len(pts)
+                if p0.stream:
+                    gtasks = emulator.prepare_stream_tasks(
+                        [p.trace for p in pts], p0.sys, [p.mode for p in pts],
+                        blooms, outs,
+                        chunk=p0.chunk or emulator.DEFAULT_STREAM_CHUNK,
+                        collect=stream_collect)
+                else:
+                    # policy groups never mix with staged/legacy points
+                    # (their group_key carries a fifth, policy element)
+                    pkw = {} if p0.policy is None else dict(
+                        policies=[p.policy for p in pts],
+                        policy_costs=[p.policy_cost for p in pts])
+                    gtasks = emulator.prepare_tasks(
+                        [p.trace for p in pts], p0.sys, [p.mode for p in pts],
+                        blooms, outs, **pkw)
+                if ckpt_path is not None:
+                    for gt in gtasks:
+                        gt.finalize = _checkpointed(gt.finalize, outs,
+                                                    ckpt_path)
+                tasks += gtasks
+                merges.append((idxs, pts, outs, gtasks))
 
-        failures = executor.execute(
-            tasks, serial=serial, timeout=timeout, retries=retries,
-            raise_on_error=False)
-        fail_by_task = {id(f.task): f for f in failures}
-        failed_groups = sum(
-            1 for m in merges if any(id(t) in fail_by_task for t in m[3]))
-        self.last_run = {
-            "groups": len(groups), "loaded": loaded,
-            "computed": len(groups) - loaded - failed_groups,
-            "failed": failed_groups, "failures": failures,
-        }
-        if failures and on_error == "raise":
-            raise executor.ExecutionError(failures)
+            failures = executor.execute(
+                tasks, serial=serial, timeout=timeout, retries=retries,
+                raise_on_error=False)
+            fail_by_task = {id(f.task): f for f in failures}
+            failed_groups = sum(
+                1 for m in merges if any(id(t) in fail_by_task for t in m[3]))
+            self.last_run = {
+                "groups": len(groups), "loaded": loaded,
+                "computed": len(groups) - loaded - failed_groups,
+                "failed": failed_groups, "failures": failures,
+            }
+            if failures and on_error == "raise":
+                raise executor.ExecutionError(failures)
 
-        for idxs, pts, outs, gtasks in merges:
-            gfail = next((fail_by_task[id(t)] for t in gtasks
-                          if id(t) in fail_by_task), None)
-            for p, i, out in zip(pts, idxs, outs):
-                if out is None:
-                    # quarantined: the group's task raised (or timed
-                    # out) before finalizing this point
-                    e = gfail.error if gfail is not None else None
-                    results[i] = {
-                        "error": str(e) if e is not None else "not computed",
-                        "error_type": type(e).__name__ if e is not None
-                        else "Unknown",
-                        "group": gfail.label if gfail is not None else "",
-                        **p.meta}
-                    continue
-                clash = set(out) & set(p.meta)
-                if clash:  # ValueError, not assert: survives python -O
-                    raise ValueError(
-                        f"meta keys shadow emulator result fields: "
-                        f"{sorted(clash)}")
-                results[i] = {**out, **p.meta}
+            for idxs, pts, outs, gtasks in merges:
+                gfail = next((fail_by_task[id(t)] for t in gtasks
+                              if id(t) in fail_by_task), None)
+                for p, i, out in zip(pts, idxs, outs):
+                    if out is None:
+                        # quarantined: the group's task raised (or timed
+                        # out) before finalizing this point
+                        e = gfail.error if gfail is not None else None
+                        results[i] = {
+                            "error": (str(e) if e is not None
+                                      else "not computed"),
+                            "error_type": type(e).__name__ if e is not None
+                            else "Unknown",
+                            "group": gfail.label if gfail is not None else "",
+                            **p.meta}
+                        continue
+                    clash = set(out) & set(p.meta)
+                    if clash:  # ValueError, not assert: survives python -O
+                        raise ValueError(
+                            f"meta keys shadow emulator result fields: "
+                            f"{sorted(clash)}")
+                    results[i] = {**out, **p.meta}
+            spans.count_requests(sp, results)
         return results
 
     def n_groups(self) -> int:

@@ -34,7 +34,7 @@ is a predicated point-scatter ``arr.at[i].set(where(pred, new, arr[i]))``
 and every read is a point gather. A whole trace therefore costs
 O(slots * Q), linear in the trace, where the slot count is the exact
 per-batch budget below. The pre-optimization engine (kept verbatim as
-:func:`run_ref` / ``_run_core_ref`` for A/B tests and benchmarks) instead
+:func:`run_ref` / ``_run_core_ref`` for A/B tests) instead
 paid full-length predicated selects per slot — O(bucket) work per slot,
 O(bucket^2) per trace.
 
@@ -85,9 +85,9 @@ Entry points:
   cold compiles entirely via
   :func:`repro.utils.jax_compat.enable_persistent_compile_cache`.
 * :func:`run_ref` / :func:`run_ref_many` — the pre-optimization
-  O(bucket)-per-slot engine, kept only to pin bit-exactness and to
-  measure the steady-state speedup in ``benchmarks/run.py --section
-  sim_speed``.
+  O(bucket)-per-slot engine, kept only to pin bit-exactness and as
+  the slow side of an A/B against the fast core (the chip benchmark is
+  ``bench/run.py``).
 * :func:`run_stream` / :func:`run_stream_many` — constant-memory
   streaming drivers for unbounded traces: the same slot body scans
   fixed-size windows of ``chunk`` requests while an explicit
@@ -116,7 +116,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dram, executor, faults as faultmod, smcprog
+from repro.core import dram, executor, faults as faultmod, smcprog, spans
 from repro.core.bloom import bloom_probe_jnp
 from repro.core.dram import NOP, WRITE
 from repro.core.timescale import SystemConfig
@@ -552,8 +552,8 @@ def _run_core(kind, bank, row, delta, dep, sys: SystemConfig, mode: str,
 # ---------------------------------------------------------------------------
 # Reference engine: the pre-optimization core. O(bucket) work per slot
 # (full-length predicated selects), uniform 2*bucket+4 budget. Kept ONLY
-# to pin bit-exactness (tests/test_property.py) and to measure the
-# steady-state speedup (benchmarks --section sim_speed). Do not use for
+# to pin bit-exactness (tests/test_property.py) and as the slow side of
+# an A/B against the fast core. Do not use for
 # new work. Semantic changes are forbidden EXCEPT the ones the fast core
 # must stay bit-identical under: the PR-4 policy-VM branch, the
 # last_bank carry it reads, and the idle-hop empty-queue fix — all
@@ -1275,70 +1275,77 @@ def prepare_tasks(traces: Sequence[Trace], sys: SystemConfig,
     executable per (trace-bucket, mode, table-bucket) evaluates the
     whole grid, however many distinct programs it holds.
     """
-    traces = list(traces)
-    n = len(traces)
-    modes = _check_modes([mode] * n if isinstance(mode, str) else mode, n)
-    blooms = _normalize_blooms(blooms, n)
-    pol = _normalize_policies(policies, policy_costs, sys, n)
+    tctx = spans.context()
+    with spans.span("emu.plan"):
+        traces = list(traces)
+        n = len(traces)
+        modes = _check_modes([mode] * n if isinstance(mode, str) else mode, n)
+        blooms = _normalize_blooms(blooms, n)
+        pol = _normalize_policies(policies, policy_costs, sys, n)
 
-    groups: dict = {}  # (bucket, normalized mode, table bucket) -> [idx]
-    for i, tr in enumerate(traces):
-        lb = None if pol is None else smcprog.table_bucket(pol[0][i].n_ops)
-        groups.setdefault(
-            (_bucket(tr.n), _norm_mode(modes[i]), lb), []).append(i)
+        groups: dict = {}  # (bucket, normalized mode, table bucket) -> [idx]
+        for i, tr in enumerate(traces):
+            lb = None if pol is None else smcprog.table_bucket(pol[0][i].n_ops)
+            groups.setdefault(
+                (_bucket(tr.n), _norm_mode(modes[i]), lb), []).append(i)
 
-    tasks: List[executor.GroupTask] = []
-    for (bucket, gmode, lb), idxs in groups.items():
-        slots = None if ref else slot_budget(
-            bucket, max(traces[i].n_real for i in idxs))
-        gsys = sys if lb is None else _policy_rt_sys(sys)
-        key = compile_key(bucket, len(idxs), gsys, gmode, blooms, slots, lb)
-        fn = _batched_fn(key, ref=ref).prime()
+        tasks: List[executor.GroupTask] = []
+        for (bucket, gmode, lb), idxs in groups.items():
+            reals = [traces[i].n_real for i in idxs]
+            slots = None if ref else slot_budget(bucket, max(reals))
+            gsys = sys if lb is None else _policy_rt_sys(sys)
+            key = compile_key(bucket, len(idxs), gsys, gmode, blooms, slots, lb)
+            fn = _batched_fn(key, ref=ref).prime()
 
-        def pack(idxs=idxs, bucket=bucket, lb=lb):
-            padded = [pad_trace(traces[i], bucket) for i in idxs]
+            def pack(idxs=idxs, bucket=bucket, lb=lb):
+                padded = [pad_trace(traces[i], bucket) for i in idxs]
+                bb = _batch_bucket(len(idxs))
+                if bb > len(idxs):  # all-NOP filler rows, discarded below
+                    filler = Trace.of(np.full(bucket, 4), np.zeros(bucket),
+                                      np.zeros(bucket), np.zeros(bucket))
+                    padded += [filler] * (bb - len(idxs))
+                stacked = [jnp.asarray(np.stack([getattr(p, f) for p in padded]))
+                           for f in ("kind", "bank", "row", "delta", "dep")]
+                if blooms is None:
+                    args = tuple(stacked)
+                elif isinstance(blooms, tuple):
+                    args = (*stacked, jnp.asarray(blooms[0]))
+                else:
+                    words = np.stack([np.asarray(blooms[i][0]) for i in idxs])
+                    if bb > len(idxs):
+                        words = np.concatenate(
+                            [words, np.repeat(words[:1], bb - len(idxs), axis=0)])
+                    args = (*stacked, jnp.asarray(words))
+                if lb is not None:
+                    tables = np.stack(
+                        [smcprog.pack_program(pol[0][i], lb) for i in idxs])
+                    cost = np.asarray(
+                        [_policy_cost_pair(sys, pol[1][i]) for i in idxs],
+                        np.int32)
+                    if bb > len(idxs):  # filler rows repeat row 0 (discarded)
+                        tables = np.concatenate(
+                            [tables,
+                             np.repeat(tables[:1], bb - len(idxs), axis=0)])
+                        cost = np.concatenate(
+                            [cost, np.repeat(cost[:1], bb - len(idxs), axis=0)])
+                    args = (*args, jnp.asarray(tables), jnp.asarray(cost))
+                return args, padded
+
+            def finalize(out, padded, idxs=idxs):
+                for j, i in enumerate(idxs):
+                    row = {kk: v[j] for kk, v in out.items()}
+                    results[i] = _finalize(row, padded[j], sys, modes[i])
+
+            ptag = "" if lb is None else f":pol{lb}"
             bb = _batch_bucket(len(idxs))
-            if bb > len(idxs):  # all-NOP filler rows, discarded below
-                filler = Trace.of(np.full(bucket, 4), np.zeros(bucket),
-                                  np.zeros(bucket), np.zeros(bucket))
-                padded += [filler] * (bb - len(idxs))
-            stacked = [jnp.asarray(np.stack([getattr(p, f) for p in padded]))
-                       for f in ("kind", "bank", "row", "delta", "dep")]
-            if blooms is None:
-                args = tuple(stacked)
-            elif isinstance(blooms, tuple):
-                args = (*stacked, jnp.asarray(blooms[0]))
-            else:
-                words = np.stack([np.asarray(blooms[i][0]) for i in idxs])
-                if bb > len(idxs):
-                    words = np.concatenate(
-                        [words, np.repeat(words[:1], bb - len(idxs), axis=0)])
-                args = (*stacked, jnp.asarray(words))
-            if lb is not None:
-                tables = np.stack(
-                    [smcprog.pack_program(pol[0][i], lb) for i in idxs])
-                cost = np.asarray(
-                    [_policy_cost_pair(sys, pol[1][i]) for i in idxs],
-                    np.int32)
-                if bb > len(idxs):  # filler rows repeat row 0 (discarded)
-                    tables = np.concatenate(
-                        [tables,
-                         np.repeat(tables[:1], bb - len(idxs), axis=0)])
-                    cost = np.concatenate(
-                        [cost, np.repeat(cost[:1], bb - len(idxs), axis=0)])
-                args = (*args, jnp.asarray(tables), jnp.asarray(cost))
-            return args, padded
-
-        def finalize(out, padded, idxs=idxs):
-            for j, i in enumerate(idxs):
-                row = {kk: v[j] for kk, v in out.items()}
-                results[i] = _finalize(row, padded[j], sys, modes[i])
-
-        ptag = "" if lb is None else f":pol{lb}"
-        tasks.append(executor.GroupTask(
-            fn=fn, pack=pack, finalize=finalize,
-            label=f"b{bucket}x{len(idxs)}:{gmode}{ptag}",
-            cost=(slots or 2 * bucket + 4) * _batch_bucket(len(idxs))))
+            tasks.append(executor.GroupTask(
+                fn=fn, pack=pack, finalize=finalize,
+                label=f"b{bucket}x{len(idxs)}:{gmode}{ptag}",
+                cost=(slots or 2 * bucket + 4) * bb,
+                counts={"slots": slots or 2 * bucket + 4, "lanes": bb,
+                        "requests": sum(reals),
+                        "shards": max(_shard_count(bb), 1)},
+                trace_ctx=tctx))
     return tasks
 
 
@@ -1369,9 +1376,11 @@ def _run_grouped(traces: Sequence[Trace], sys: SystemConfig,
     (the executor only changes wall-clock interleaving)."""
     traces = list(traces)
     results: List[Optional[dict]] = [None] * len(traces)
-    tasks = prepare_tasks(traces, sys, mode, blooms, results, ref=ref,
-                          policies=policies, policy_costs=policy_costs)
-    _execute_entry_point(tasks, serial)
+    with spans.span("emu.call") as sp:
+        tasks = prepare_tasks(traces, sys, mode, blooms, results, ref=ref,
+                              policies=policies, policy_costs=policy_costs)
+        _execute_entry_point(tasks, serial)
+        spans.count_requests(sp, results)
     return results
 
 
@@ -1453,7 +1462,7 @@ def run(trace: Trace, sys: SystemConfig, mode: str = "ts",
 
     'reference' is the Sec. 6 RTL reference system: a hardware memory
     controller at the modeled clock. Its math must coincide with 'ts' —
-    that coincidence (validated in tests/benchmarks) IS the paper's
+    that coincidence (validated in tests) IS the paper's
     time-scaling accuracy claim.
 
     A thin wrapper over a :func:`run_many` batch of one — single-trace
@@ -1881,145 +1890,152 @@ def prepare_stream_tasks(streams: Sequence, sys: SystemConfig,
     ``policy_costs`` are per-STREAM (one program per stream row, see
     :func:`_normalize_policies`); the packed tables are per-group
     constants appended to every window's arguments."""
-    streams = list(streams)
-    n = len(streams)
-    modes = _check_modes([mode] * n if isinstance(mode, str) else mode, n)
-    blooms = _normalize_blooms(blooms, n)
-    pol = _normalize_policies(policies, policy_costs, sys, n)
-    H = stream_halo(sys, dep_max)
-    if not isinstance(chunk, (int, np.integer)) or isinstance(chunk, bool) \
-            or chunk < H:
-        raise ValueError(
-            f"stream chunk must be an int >= halo ({H} = {_FRONTIER_UPTO} "
-            f"+ max(window={sys.window}, dep_max={dep_max})) so the final "
-            f"window drains the whole tail in-budget, got {chunk!r}")
-    if collect not in ("full", "aggregate"):
-        raise ValueError(
-            f"collect must be 'full' or 'aggregate', got {collect!r}")
-    chunk = int(chunk)
-    SL = stream_slot_budget(chunk, sys)
-    L = chunk + H
+    tctx = spans.context()
+    with spans.span("emu.plan"):
+        streams = list(streams)
+        n = len(streams)
+        modes = _check_modes([mode] * n if isinstance(mode, str) else mode, n)
+        blooms = _normalize_blooms(blooms, n)
+        pol = _normalize_policies(policies, policy_costs, sys, n)
+        H = stream_halo(sys, dep_max)
+        if not isinstance(chunk, (int, np.integer)) or isinstance(chunk, bool) \
+                or chunk < H:
+            raise ValueError(
+                f"stream chunk must be an int >= halo ({H} = {_FRONTIER_UPTO} "
+                f"+ max(window={sys.window}, dep_max={dep_max})) so the final "
+                f"window drains the whole tail in-budget, got {chunk!r}")
+        if collect not in ("full", "aggregate"):
+            raise ValueError(
+                f"collect must be 'full' or 'aggregate', got {collect!r}")
+        chunk = int(chunk)
+        SL = stream_slot_budget(chunk, sys)
+        L = chunk + H
 
-    groups: dict = {}
-    for i in range(n):
-        lb = None if pol is None else smcprog.table_bucket(pol[0][i].n_ops)
-        groups.setdefault((_norm_mode(modes[i]), lb), []).append(i)
+        groups: dict = {}
+        for i in range(n):
+            lb = None if pol is None else smcprog.table_bucket(pol[0][i].n_ops)
+            groups.setdefault((_norm_mode(modes[i]), lb), []).append(i)
 
-    tasks: List[executor.StreamTask] = []
-    for (gmode, lb), idxs in groups.items():
-        gsys = sys if lb is None else _policy_rt_sys(sys)
-        key = stream_compile_key(chunk, len(idxs), gsys, gmode, blooms,
-                                 dep_max, lb)
-        fn = _stream_fn(key).prime()
-        bb = _batch_bucket(len(idxs))
-        if blooms is None:
-            wargs = ()
-        elif isinstance(blooms, tuple):
-            wargs = (jnp.asarray(blooms[0]),)
-        else:
-            words = np.stack([np.asarray(blooms[i][0]) for i in idxs])
-            if bb > len(idxs):
-                words = np.concatenate(
-                    [words, np.repeat(words[:1], bb - len(idxs), axis=0)])
-            wargs = (jnp.asarray(words),)
-        if lb is not None:  # per-group policy operands, shared by windows
-            tables = np.stack(
-                [smcprog.pack_program(pol[0][i], lb) for i in idxs])
-            cost = np.asarray(
-                [_policy_cost_pair(sys, pol[1][i]) for i in idxs], np.int32)
-            if bb > len(idxs):
-                tables = np.concatenate(
-                    [tables, np.repeat(tables[:1], bb - len(idxs), axis=0)])
-                cost = np.concatenate(
-                    [cost, np.repeat(cost[:1], bb - len(idxs), axis=0)])
-            wargs = wargs + (jnp.asarray(tables), jnp.asarray(cost))
+        tasks: List[executor.StreamTask] = []
+        for (gmode, lb), idxs in groups.items():
+            gsys = sys if lb is None else _policy_rt_sys(sys)
+            key = stream_compile_key(chunk, len(idxs), gsys, gmode, blooms,
+                                     dep_max, lb)
+            fn = _stream_fn(key).prime()
+            bb = _batch_bucket(len(idxs))
+            if blooms is None:
+                wargs = ()
+            elif isinstance(blooms, tuple):
+                wargs = (jnp.asarray(blooms[0]),)
+            else:
+                words = np.stack([np.asarray(blooms[i][0]) for i in idxs])
+                if bb > len(idxs):
+                    words = np.concatenate(
+                        [words, np.repeat(words[:1], bb - len(idxs), axis=0)])
+                wargs = (jnp.asarray(words),)
+            if lb is not None:  # per-group policy operands, shared by windows
+                tables = np.stack(
+                    [smcprog.pack_program(pol[0][i], lb) for i in idxs])
+                cost = np.asarray(
+                    [_policy_cost_pair(sys, pol[1][i]) for i in idxs], np.int32)
+                if bb > len(idxs):
+                    tables = np.concatenate(
+                        [tables, np.repeat(tables[:1], bb - len(idxs), axis=0)])
+                    cost = np.concatenate(
+                        [cost, np.repeat(cost[:1], bb - len(idxs), axis=0)])
+                wargs = wargs + (jnp.asarray(tables), jnp.asarray(cost))
 
-        def pack(idxs=idxs, bb=bb):
-            ctx = {
-                "chunkers": [_Chunker(streams[i], chunk, dep_max)
-                             for i in idxs],
-                "accs": [_StreamAccum(collect, H) for _ in idxs],
-                # index of the freeze-lifted final window; written by
-                # windows() BEFORE that window's args are queued, so the
-                # (possibly prefetching) consumer always sees it in time
-                "final_idx": None,
-                "fed": 0,
-            }
-            return _stream_init(chunk, H, sys, batch=bb), ctx
+            def pack(idxs=idxs, bb=bb):
+                ctx = {
+                    "chunkers": [_Chunker(streams[i], chunk, dep_max)
+                                 for i in idxs],
+                    "accs": [_StreamAccum(collect, H) for _ in idxs],
+                    # index of the freeze-lifted final window; written by
+                    # windows() BEFORE that window's args are queued, so the
+                    # (possibly prefetching) consumer always sees it in time
+                    "final_idx": None,
+                    "fed": 0,
+                }
+                return _stream_init(chunk, H, sys, batch=bb), ctx
 
-        def windows(ctx, bb=bb, wargs=wargs):
-            # the window whose assembly exhausts every chunker is the
-            # final one: it ships with the freeze LIFTED (final=1) and
-            # drains the whole tail in-budget — no separate flush
-            # dispatch (SL covers a full fresh chunk plus the carried
-            # halo, the exact worst case)
-            chunkers = ctx["chunkers"]
-            filler = _nop_fields(chunk)
-            k = 0
-            while not all(c.done for c in chunkers):
-                blocks = [c.next_block() for c in chunkers]
-                blocks += [filler] * (bb - len(blocks))
-                final = all(c.done for c in chunkers)
-                if final:
-                    ctx["final_idx"] = k
-                yield tuple(
-                    jnp.asarray(np.stack([b[i] for b in blocks]))
-                    for i in range(5)) + (jnp.int32(final),) + wargs
-                k += 1
-            if k == 0:  # every stream empty: one all-NOP final window
-                ctx["final_idx"] = 0
-                blocks = [filler] * bb
-                yield tuple(
-                    jnp.asarray(np.stack([b[i] for b in blocks]))
-                    for i in range(5)) + (jnp.int32(1),) + wargs
+            def windows(ctx, bb=bb, wargs=wargs):
+                # the window whose assembly exhausts every chunker is the
+                # final one: it ships with the freeze LIFTED (final=1) and
+                # drains the whole tail in-budget — no separate flush
+                # dispatch (SL covers a full fresh chunk plus the carried
+                # halo, the exact worst case). Each window comes with its
+                # fresh non-NOP requests, for the dispatch span's count.
+                chunkers = ctx["chunkers"]
+                filler = _nop_fields(chunk)
+                k = 0
+                while not all(c.done for c in chunkers):
+                    blocks = [c.next_block() for c in chunkers]
+                    fresh = sum(int(np.count_nonzero(b[0] != NOP))
+                                for b in blocks)
+                    blocks += [filler] * (bb - len(blocks))
+                    final = all(c.done for c in chunkers)
+                    if final:
+                        ctx["final_idx"] = k
+                    yield tuple(
+                        jnp.asarray(np.stack([b[i] for b in blocks]))
+                        for i in range(5)) + (jnp.int32(final),) + wargs, fresh
+                    k += 1
+                if k == 0:  # every stream empty: one all-NOP final window
+                    ctx["final_idx"] = 0
+                    blocks = [filler] * bb
+                    yield tuple(
+                        jnp.asarray(np.stack([b[i] for b in blocks]))
+                        for i in range(5)) + (jnp.int32(1),) + wargs, 0
 
-        def consume(out, ctx, idxs=idxs):
-            kind_blk, issue_blk, resp_blk, ptr = out
-            final = ctx["final_idx"] == ctx["fed"]
-            ctx["fed"] += 1
-            # interior windows retire exactly [0, chunk); the final one
-            # keeps its whole [0, L) carry (tail included — that is the
-            # flush)
-            keep = L if final else chunk
-            for j, acc in enumerate(ctx["accs"]):
-                acc.feed(kind_blk[j, :keep], issue_blk[j, :keep],
-                         resp_blk[j, :keep])
-            if not final:
-                lag = ptr[:len(idxs)] <= (L - _FRONTIER_UPTO)
-                if lag.any():
-                    raise RuntimeError(
-                        f"streaming invariant violated: issue frontier "
-                        f"fell behind the window "
-                        f"(ptr={ptr[:len(idxs)].tolist()}, window={L}, "
-                        f"slots={SL}) — slot budget too small")
+            def consume(out, ctx, idxs=idxs):
+                kind_blk, issue_blk, resp_blk, ptr = out
+                final = ctx["final_idx"] == ctx["fed"]
+                ctx["fed"] += 1
+                # interior windows retire exactly [0, chunk); the final one
+                # keeps its whole [0, L) carry (tail included — that is the
+                # flush)
+                keep = L if final else chunk
+                for j, acc in enumerate(ctx["accs"]):
+                    acc.feed(kind_blk[j, :keep], issue_blk[j, :keep],
+                             resp_blk[j, :keep])
+                if not final:
+                    lag = ptr[:len(idxs)] <= (L - _FRONTIER_UPTO)
+                    if lag.any():
+                        raise RuntimeError(
+                            f"streaming invariant violated: issue frontier "
+                            f"fell behind the window "
+                            f"(ptr={ptr[:len(idxs)].tolist()}, window={L}, "
+                            f"slots={SL}) — slot budget too small")
 
-        def finalize(final_state, ctx, idxs=idxs):
-            e = final_state.emu
-            hits = np.asarray(e.hits)
-            served = np.asarray(e.served_n)
-            dram_now = np.asarray(e.dram_now)
-            smc = np.asarray(e.smc_fpga_cycles)
-            # the fault carry rides EmulatorState through every window
-            # untouched by the shift, so the final window's state IS the
-            # whole stream's flip record (bit-identical to single-shot)
-            fhost = (None if sys.faults is None else
-                     jax.tree_util.tree_map(np.asarray, e.faults))
-            for j, i in enumerate(idxs):
-                results[i] = ctx["accs"][j].result(
-                    ctx["chunkers"][j].n, int(hits[j]), int(served[j]),
-                    int(dram_now[j]), int(smc[j]), sys, modes[i])
-                if fhost is not None:
-                    frow = {kk: v[j] for kk, v in fhost.items()}
-                    results[i].update(faultmod.fault_result_fields(frow))
-                    results[i]["bit_error_rate"] = \
-                        int(frow["vptr"]) / max(int(served[j]), 1)
+            def finalize(final_state, ctx, idxs=idxs):
+                e = final_state.emu
+                hits = np.asarray(e.hits)
+                served = np.asarray(e.served_n)
+                dram_now = np.asarray(e.dram_now)
+                smc = np.asarray(e.smc_fpga_cycles)
+                # the fault carry rides EmulatorState through every window
+                # untouched by the shift, so the final window's state IS the
+                # whole stream's flip record (bit-identical to single-shot)
+                fhost = (None if sys.faults is None else
+                         jax.tree_util.tree_map(np.asarray, e.faults))
+                for j, i in enumerate(idxs):
+                    results[i] = ctx["accs"][j].result(
+                        ctx["chunkers"][j].n, int(hits[j]), int(served[j]),
+                        int(dram_now[j]), int(smc[j]), sys, modes[i])
+                    if fhost is not None:
+                        frow = {kk: v[j] for kk, v in fhost.items()}
+                        results[i].update(faultmod.fault_result_fields(frow))
+                        results[i]["bit_error_rate"] = \
+                            int(frow["vptr"]) / max(int(served[j]), 1)
 
-        ptag = "" if lb is None else f":pol{lb}"
-        tasks.append(executor.StreamTask(
-            fn=fn, pack=pack, windows=windows, consume=consume,
-            finalize=finalize,
-            label=f"stream:c{chunk}x{len(idxs)}:{gmode}{ptag}",
-            cost=SL * bb))
+            ptag = "" if lb is None else f":pol{lb}"
+            tasks.append(executor.StreamTask(
+                fn=fn, pack=pack, windows=windows, consume=consume,
+                finalize=finalize,
+                label=f"stream:c{chunk}x{len(idxs)}:{gmode}{ptag}",
+                cost=SL * bb, trace_ctx=tctx,
+                # the window runner is never shard_mapped: one device
+                counts={"slots": SL, "lanes": bb, "shards": 1}))
     return tasks
 
 
@@ -2057,11 +2073,13 @@ def run_stream_many(streams: Sequence, sys: SystemConfig,
     the accumulator."""
     streams = list(streams)
     results: List[Optional[dict]] = [None] * len(streams)
-    tasks = prepare_stream_tasks(streams, sys, mode, blooms, results,
-                                 chunk=chunk, dep_max=dep_max,
-                                 collect=collect, policies=policies,
-                                 policy_costs=policy_costs)
-    _execute_entry_point(tasks, serial)
+    with spans.span("emu.call") as sp:
+        tasks = prepare_stream_tasks(streams, sys, mode, blooms, results,
+                                     chunk=chunk, dep_max=dep_max,
+                                     collect=collect, policies=policies,
+                                     policy_costs=policy_costs)
+        _execute_entry_point(tasks, serial)
+        spans.count_requests(sp, results)
     return results
 
 

@@ -21,8 +21,8 @@ Determinism contract:
   indices of a shared list), so concurrent finalization needs no lock.
 * Execution is bit-identical to the serial loop by construction: the
   same executable runs on the same packed arrays; only wall-clock
-  interleaving changes. ``execute(tasks, serial=True)`` keeps the PR 4
-  in-order loop for A/B (``benchmarks --section executor_speed``).
+  interleaving changes. ``execute(tasks, serial=True)`` keeps the
+  in-order loop for A/B.
 
 The pool is module-level and lazily built (``REPRO_EXEC_WORKERS`` caps
 it, default ``min(cpu_count, 8)``); :func:`set_workers` resizes it.
@@ -42,6 +42,8 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import spans
+
 __all__ = ["GroupTask", "StreamTask", "TaskFailure", "ExecutionError",
            "execute", "submit_task", "set_workers", "workers",
            "shutdown", "is_shutdown"]
@@ -58,12 +60,18 @@ class GroupTask:
     caller's result slots. ``pack`` and ``finalize`` run on a worker
     thread under :func:`execute`'s overlapped mode — keep them free of
     shared mutable state beyond the disjoint result slots.
+
+    ``counts`` are the ``emu.dispatch`` span's counts and ``trace_ctx``
+    the caller's :func:`repro.core.spans.context` at preparation, which
+    the worker re-enters (see :mod:`repro.core.spans`).
     """
     fn: Callable[..., Any]
     pack: Callable[[], Tuple[tuple, Any]]
     finalize: Callable[[dict, Any], None]
     label: str = ""
     cost: int = 0   # relative work hint (e.g. slots * batch) for LPT order
+    counts: dict = dataclasses.field(default_factory=dict)
+    trace_ctx: Any = None
 
     # pack() re-pads and re-stacks from the immutable prepared traces and
     # finalize() overwrites the same disjoint slots, so a failed attempt
@@ -71,10 +79,15 @@ class GroupTask:
     retryable = True
 
     def run(self) -> None:
-        args, ctx = self.pack()                      # host: pad + stack
-        out = self.fn(*args)                         # device: the scan
-        out = {k: np.asarray(v) for k, v in out.items()}  # gather (blocks)
-        self.finalize(out, ctx)
+        with spans.attach(self.trace_ctx):
+            with spans.span("emu.pack"):
+                args, ctx = self.pack()                  # host: pad + stack
+            with spans.span("emu.dispatch", **self.counts):
+                out = self.fn(*args)                     # device: the scan
+            with spans.span("emu.wait"):                 # gather (blocks)
+                out = {k: np.asarray(v) for k, v in out.items()}
+            with spans.span("emu.finalize"):
+                self.finalize(out, ctx)
 
 
 @dataclasses.dataclass
@@ -83,8 +96,8 @@ class StreamTask:
     single dispatch (see ``repro.core.emulator.prepare_stream_tasks``).
 
     ``pack`` builds the initial carried state plus a host context;
-    ``windows(ctx)`` yields one argument tuple per trace window (the
-    last one freeze-lifted to drain the tail in place);
+    ``windows(ctx)`` yields one ``(args, requests)`` pair per trace
+    window (the last one freeze-lifted to drain the tail in place);
     ``fn(state, *args)`` is the resolved window
     executable returning ``(new_state, emitted)``; ``consume`` receives
     each window's gathered NumPy emission; ``finalize`` receives the
@@ -100,14 +113,20 @@ class StreamTask:
     stream/group tasks across workers. Same determinism contract as
     :class:`GroupTask`: disjoint result slots, prepared on the
     caller's thread; prefetch changes wall-clock interleaving only,
-    never the window sequence."""
+    never the window sequence.
+
+    ``requests`` are the requests a window brings fresh, which its
+    ``emu.dispatch`` span counts beside ``counts``. ``trace_ctx`` is as
+    on :class:`GroupTask`; the prefetch thread re-enters it too."""
     fn: Callable[..., Any]
     pack: Callable[[], Tuple[Any, Any]]
-    windows: Callable[[Any], Any]        # ctx -> iterable of arg tuples
+    windows: Callable[[Any], Any]  # ctx -> iterable of (arg tuple, requests)
     consume: Callable[[tuple, Any], None]
     finalize: Callable[[Any, Any], None]
     label: str = ""
     cost: int = 0
+    counts: dict = dataclasses.field(default_factory=dict)
+    trace_ctx: Any = None
 
     # a failed window loop cannot be replayed: the stream iterators and
     # chunker buffers are partially consumed — never auto-retry
@@ -116,9 +135,15 @@ class StreamTask:
     _PREFETCH = 2  # max staged windows in flight (bounds memory)
 
     def run(self) -> None:
+        with spans.attach(self.trace_ctx):
+            self._run()
+
+    def _run(self) -> None:
         import queue as _queue
 
-        state, ctx = self.pack()
+        with spans.span("emu.pack"):
+            state, ctx = self.pack()
+        tctx = spans.context()
         q: _queue.Queue = _queue.Queue(maxsize=self._PREFETCH)
         done, stop = object(), threading.Event()
 
@@ -135,13 +160,19 @@ class StreamTask:
             return False
 
         def feed() -> None:
-            try:
-                for args in self.windows(ctx):
-                    if not put(args):
-                        return          # consumer bailed; stop generating
-                put(done)
-            except BaseException as e:  # surface on the consuming thread
-                put(e)
+            with spans.attach(tctx):
+                try:
+                    it = iter(self.windows(ctx))
+                    while True:
+                        with spans.span("emu.assemble"):
+                            win = next(it, done)
+                        if win is done:
+                            break
+                        if not put(win):
+                            return      # consumer bailed; stop generating
+                    put(done)
+                except BaseException as e:  # surface on the consumer
+                    put(e)
 
         th = threading.Thread(target=feed, daemon=True,
                               name="repro-stream-prefetch")
@@ -149,7 +180,8 @@ class StreamTask:
         try:
             while True:
                 try:
-                    args = q.get(timeout=0.2)
+                    with spans.span("emu.prefetch_wait"):
+                        win = q.get(timeout=0.2)
                 except _queue.Empty:
                     # a poisoned feeder (interpreter shutdown) never
                     # delivers its `done` sentinel — fail the window
@@ -161,12 +193,17 @@ class StreamTask:
                             f"aborted: executor shut down at interpreter "
                             f"exit")
                     continue
-                if args is done:
+                if win is done:
                     break
-                if isinstance(args, BaseException):
-                    raise args
-                state, out = self.fn(state, *args)   # device: one window
-                self.consume(tuple(np.asarray(o) for o in out), ctx)
+                if isinstance(win, BaseException):
+                    raise win
+                args, fresh = win
+                with spans.span("emu.dispatch", requests=fresh, **self.counts):
+                    state, out = self.fn(state, *args)   # device: one window
+                with spans.span("emu.wait"):
+                    out = tuple(np.asarray(o) for o in out)
+                with spans.span("emu.consume"):
+                    self.consume(out, ctx)
         finally:
             # deterministic shutdown: signal stop, then DRAIN the queue
             # while joining — a feeder sitting in q.put() frees its slot
@@ -189,7 +226,8 @@ class StreamTask:
                     f"stream prefetch thread for {self.label or 'task'!r} "
                     f"did not stop within 5s (window generator blocked); "
                     f"leaking a daemon thread", RuntimeWarning)
-        self.finalize(state, ctx)
+        with spans.span("emu.finalize"):
+            self.finalize(state, ctx)
 
 
 def _env_int(name: str, default: int) -> int:

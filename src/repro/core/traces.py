@@ -214,13 +214,11 @@ def synthetic_stream(n_requests: int, window: int = 4096, seed: int = 0,
                      kinds: int = 2, delta_max: int = 8,
                      dep_max: int = 2) -> Iterator[Trace]:
     """Unbounded-style random request stream, yielded one ``window`` at
-    a time so the whole trace never materializes — the 1M-request
-    steady-state workload of ``benchmarks --section streaming``. The
-    per-window RNG is seeded by (seed, window index): the stream is
-    reproducible and restartable, and its distribution matches the
-    8x4000 single-shot steady-state traces in benchmarks/paper.py
-    (uniform banks/rows, read/write mix, delta in [1, delta_max),
-    dep in [0, dep_max))."""
+    a time so the whole trace never materializes. The per-window RNG is
+    seeded by (seed, window index): the stream is reproducible and
+    restartable, with uniform banks/rows, a read/write mix, delta in
+    [1, delta_max) and dep in [0, dep_max). The chip benchmark
+    (``bench/run.py``) replays PolyBench traces instead."""
     emitted = 0
     k = 0
     while emitted < n_requests:

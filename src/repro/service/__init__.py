@@ -27,7 +27,7 @@ Over a socket (one process owns the warm engine, many attach)::
     ...
     cli = SweepClient(address=("127.0.0.1", 7421), name="bob")
 
-See ``examples/sweep_service.py`` and ``benchmarks --section service``.
+See ``examples/sweep_service.py``.
 """
 from repro.service.server import (QueueFullError, ServerClosedError,
                                   ServiceConfig, SweepServer, load_pending)

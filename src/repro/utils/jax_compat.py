@@ -1,10 +1,12 @@
 """One place for the JAX API surface the codebase leans on.
 
-Every ``shard_map`` / ``cost_analysis`` / persistent-cache call site goes
-through these helpers instead of probing ``jax.<attr>`` itself, so a JAX
-upgrade changes exactly one file.
+Every ``shard_map`` / ``cost_analysis`` / persistent-cache / profiler-state
+call site goes through these helpers instead of probing ``jax.<attr>``
+itself, so a JAX upgrade changes exactly one file.
 
 * :data:`shard_map` — ``jax.shard_map``.
+* :func:`profiler_active` — whether a ``jax.profiler`` session is
+  recording (``TraceMe.is_enabled()``).
 * :func:`pvary` — mark a value device-varying over mesh axes inside
   ``shard_map`` (``jax.lax.pcast(..., to="varying")``).
 * :func:`cost_analysis_dict` — ``Compiled.cost_analysis()`` as one flat
@@ -22,12 +24,19 @@ import os
 from typing import Any, Dict, Optional
 
 import jax
+from jax._src.lib import _profiler
 
 shard_map = jax.shard_map
+_TraceMe = _profiler.TraceMe
 
 # <checkout>/src/repro/utils/jax_compat.py -> <checkout>
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
+
+
+def profiler_active() -> bool:
+    """True while a ``jax.profiler`` session records (on any backend)."""
+    return _TraceMe.is_enabled()
 
 
 def pvary(x, axis_names):
