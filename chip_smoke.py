@@ -39,7 +39,6 @@ before any phase. The compile cache lives in
 ``<checkout>/artifacts/xla_cache``.
 """
 import argparse
-import collections
 import hashlib
 import json
 import os
@@ -252,7 +251,7 @@ def phase_sharded(ctx: dict) -> int:
     mode, a stacked filter per trace}), sharded over four devices,
     against the same campaign on one device."""
     from repro.core import emulator, traces
-    from repro.core.campaign import Campaign
+    from repro.core.campaign import Campaign, plan_groups
 
     t, _, _, _ = trcd_setup(0)
     bloom = t.bloom_tuple
@@ -269,9 +268,9 @@ def phase_sharded(ctx: dict) -> int:
             # them, and the words shard along the batch axis
             c.add(tr, t.sys, "ts", bloom=(words, k, m_bits), n=n,
                   seed=seed, arm="stacked")
-    groups = collections.Counter(p.group_key() for p in c.points)
-    shards = {emulator._shard_count(emulator._batch_bucket(n))
-              for n in groups.values()}
+    groups = plan_groups(c.points)
+    shards = {emulator._shard_count(emulator._batch_bucket(len(idxs)))
+              for idxs in groups.values()}
     if len(c) < 64 or shards != {4}:
         raise Mismatch(f"sharded campaign: {len(c)} points, shard counts "
                        f"{shards} (need >= 64 points, all 4-way)")

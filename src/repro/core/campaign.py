@@ -8,7 +8,10 @@ point; a :class:`Campaign` instead collects the whole grid, groups
 points by compile key (trace-length bucket, ``SystemConfig``, mode,
 Bloom-filter shape), executes each group as ONE vmapped
 :func:`repro.core.emulator.run_many` call, and returns tidy per-point
-records in submission order.
+records in submission order. Points without a filter join the filtered
+points of their bucket, config and mode as lanes whose filter mask is
+off (:func:`plan_groups`), so a base-vs-reduced grid is one dispatch
+per bucket.
 
 Usage::
 
@@ -140,6 +143,50 @@ class Point:
                     emulator._bloom_shape(self.bloom))
         return emulator.group_key(self.trace.n, self.sys, self.mode,
                                   self.bloom, policy=self.policy)
+
+    def coalesce_key(self) -> tuple:
+        """:meth:`group_key` without the filter shape: the points that
+        may share one dispatch, which :func:`plan_groups` then splits by
+        filter shape. Stream points keep their group key (the window
+        runner has no lane mask)."""
+        if self.stream:
+            return self.group_key()
+        return emulator.group_key(self.trace.n, self.sys, self.mode, None,
+                                  policy=self.policy)
+
+
+def plan_groups(points: Sequence[Point]) -> Dict[tuple, List[int]]:
+    """The dispatch groups of ``points``: group key -> point indices, in
+    order of first appearance. Each point groups on its
+    :meth:`Point.group_key`, except that an unfiltered non-stream point
+    joins the filtered points of its :meth:`Point.coalesce_key` when
+    those all have one filter shape; it then rides their dispatch with
+    its lane's filter mask off. Where a coalesce key holds no filtered
+    point, or several filter shapes, the unfiltered points keep a group
+    of their own. ``Campaign.run`` and the sweep service both group by
+    this rule."""
+    shapes: Dict[tuple, set] = {}
+    for p in points:
+        if p.bloom is not None and not p.stream:
+            shapes.setdefault(p.coalesce_key(), set()).add(p.group_key())
+    groups: Dict[tuple, List[int]] = {}
+    for i, p in enumerate(points):
+        key = p.group_key()
+        if p.bloom is None and not p.stream:
+            filtered = shapes.get(p.coalesce_key(), ())
+            if len(filtered) == 1:
+                (key,) = filtered
+        groups.setdefault(key, []).append(i)
+    return groups
+
+
+def group_blooms(pts: Sequence[Point]):
+    """The ``blooms`` argument of one group's dispatch: None, one filter
+    shared by every point, or the per-point list (None for an unfiltered
+    point; :func:`repro.core.emulator.run_many` broadcasts one filter
+    object and stacks distinct ones)."""
+    blooms = [p.bloom for p in pts]
+    return blooms[0] if all(b is blooms[0] for b in blooms) else blooms
 
 
 def _group_digest(key: tuple, pts: Sequence[Point]) -> str:
@@ -345,9 +392,7 @@ class Campaign:
             raise ValueError(
                 f"on_error must be 'raise' or 'quarantine', got {on_error!r}")
         with spans.span("emu.call") as sp:
-            groups: Dict[tuple, List[int]] = {}
-            for i, p in enumerate(self.points):
-                groups.setdefault(p.group_key(), []).append(i)
+            groups = plan_groups(self.points)
             if checkpoint is not None:
                 os.makedirs(checkpoint, exist_ok=True)
 
@@ -370,11 +415,7 @@ class Campaign:
                             loaded += 1
                             merges.append((idxs, pts, outs, []))
                             continue  # finished group: zero recompute
-                blooms = None
-                if p0.bloom is not None:
-                    # one shared filter broadcasts; distinct ones stack
-                    same = all(b.bloom is p0.bloom for b in pts)
-                    blooms = p0.bloom if same else [p.bloom for p in pts]
+                blooms = group_blooms(pts)
                 outs = [None] * len(pts)
                 if p0.stream:
                     gtasks = emulator.prepare_stream_tasks(
@@ -438,4 +479,4 @@ class Campaign:
         return results
 
     def n_groups(self) -> int:
-        return len({p.group_key() for p in self.points})
+        return len(plan_groups(self.points))

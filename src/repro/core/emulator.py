@@ -68,7 +68,8 @@ Entry points:
   batch of one.
 * :func:`run_many` — a batched campaign step: pads every trace to one
   length bucket, stacks them on a leading axis, and ``jax.vmap``s the
-  scan over that axis (optionally over per-trace Bloom filters too), so
+  scan over that axis (optionally over per-trace Bloom filters and a
+  per-lane filter mask too), so
   a whole sweep shares ONE compile and ONE device dispatch. Compiled
   executables are cached at module level keyed on
   ``(bucket, slots, batch, sys, mode, bloom-shape)`` — repeated sweeps
@@ -315,7 +316,8 @@ def _issue_frontier(t_issue, t_resp, queue, kindj, delta, dep, ptr, W,
 
 def _make_slot_body(kindj, bankj, rowj, deltaj, depj, sys: SystemConfig,
                     mode: str, bloom_words, bloom_k: int, bloom_m: int,
-                    gate=None, policy_table=None, policy_cost=None):
+                    gate=None, policy_table=None, policy_cost=None,
+                    bloom_on=None):
     """Build the per-slot transition ``EmulatorState -> EmulatorState``
     over one set of trace arrays. This is THE slot body: the single-shot
     scan (:func:`_run_core`) and the streaming windows
@@ -350,7 +352,13 @@ def _make_slot_body(kindj, bankj, rowj, deltaj, depj, sys: SystemConfig,
     and the nots-mode free-running decision latency, exactly the two
     numbers the staged path bakes in from ``sys.smc_cycles_per_decision``
     (derived host-side by :func:`_policy_cost_pair`, so the int
-    arithmetic is bit-identical)."""
+    arithmetic is bit-identical).
+
+    ``bloom_on`` is the batched runners' per-lane filter mask, a traced
+    int32 operand: where it is 0 the lane activates every row at nominal
+    tRCD, exactly as without a filter, so filtered and unfiltered points
+    share one executable and one dispatch. None (the streaming window)
+    compiles the filter alone."""
     N = kindj.shape[0]
     t = sys.timing
     geo = sys.geometry
@@ -440,6 +448,8 @@ def _make_slot_body(kindj, bankj, rowj, deltaj, depj, sys: SystemConfig,
         if use_bloom:
             gid = (bankj[pick] * geo.n_rows + rowj[pick]).astype(jnp.uint32)
             weakp = bloom_probe_jnp(bloom_words, bloom_m, bloom_k, gid[None])[0]
+            if bloom_on is not None:
+                weakp = weakp | (bloom_on == 0)
             trcd_eff = jnp.where(weakp, jnp.int32(t.tRCD), jnp.int32(t.tRCD_reduced))
         nbs, t_done, hit = dram.service_request(
             st.bank, t, kindj[pick], bankj[pick], rowj[pick],
@@ -512,18 +522,19 @@ def _make_slot_body(kindj, bankj, rowj, deltaj, depj, sys: SystemConfig,
 def _run_core(kind, bank, row, delta, dep, sys: SystemConfig, mode: str,
               bloom_words, bloom_k: int, bloom_m: int,
               slots: Optional[int] = None,
-              policy_table=None, policy_cost=None):
+              policy_table=None, policy_cost=None, bloom_on=None):
     """One trace's single-shot scan: a fresh :class:`EmulatorState`
     driven through the shared slot body (:func:`_make_slot_body`) for
     the ``slots`` budget. Pure traceable function (jit/vmap applied by
     the compile cache below). ``policy_table`` / ``policy_cost`` are the
-    runtime-operand policy inputs (see :func:`_make_slot_body`)."""
+    runtime-operand policy inputs and ``bloom_on`` the lane's filter
+    mask (see :func:`_make_slot_body`)."""
     N = kind.shape[0]
     W = sys.window
     step = _make_slot_body(kind, bank, row, delta, dep, sys, mode,
                            bloom_words, bloom_k, bloom_m,
                            policy_table=policy_table,
-                           policy_cost=policy_cost)
+                           policy_cost=policy_cost, bloom_on=bloom_on)
     length = (2 * N + 4) if slots is None else slots
     state, _ = jax.lax.scan(lambda st, _: (step(st), None),
                             EmulatorState.init(N, sys), None, length=length)
@@ -556,8 +567,8 @@ def _run_core(kind, bank, row, delta, dep, sys: SystemConfig, mode: str,
 # an A/B against the fast core. Do not use for
 # new work. Semantic changes are forbidden EXCEPT the ones the fast core
 # must stay bit-identical under: the PR-4 policy-VM branch, the
-# last_bank carry it reads, and the idle-hop empty-queue fix — all
-# mirrored line-for-line from _run_core.
+# last_bank carry it reads, the idle-hop empty-queue fix and the
+# per-lane filter mask — all mirrored line-for-line from _run_core.
 # ---------------------------------------------------------------------------
 
 
@@ -590,7 +601,7 @@ def _issue_frontier_ref(t_issue, t_resp, queue, kindj, delta, dep, ptr, W,
 
 def _run_core_ref(kind, bank, row, delta, dep, sys: SystemConfig, mode: str,
                   bloom_words, bloom_k: int, bloom_m: int,
-                  policy_table=None, policy_cost=None):
+                  policy_table=None, policy_cost=None, bloom_on=None):
     N = kind.shape[0]
     t = sys.timing
     geo = sys.geometry
@@ -685,6 +696,8 @@ def _run_core_ref(kind, bank, row, delta, dep, sys: SystemConfig, mode: str,
         if use_bloom:
             gid = (bankj[pick] * geo.n_rows + rowj[pick]).astype(jnp.uint32)
             weakp = bloom_probe_jnp(bloom_words, bloom_m, bloom_k, gid[None])[0]
+            if bloom_on is not None:
+                weakp = weakp | (bloom_on == 0)
             trcd_eff = jnp.where(weakp, jnp.int32(t.tRCD), jnp.int32(t.tRCD_reduced))
         nbs, t_done, hit = dram.service_request(
             state["bank"], t, kindj[pick], bankj[pick], rowj[pick],
@@ -862,9 +875,13 @@ def _norm_mode(mode: str) -> str:
 
 def _is_bloom_triple(b) -> bool:
     """One (words_u32, k, m_bits) filter: words array + two scalars (as
-    opposed to a per-trace sequence of such triples)."""
-    return (len(b) == 3 and not isinstance(b[0], (tuple, list))
-            and np.ndim(b[1]) == 0 and np.ndim(b[2]) == 0)
+    opposed to a per-trace sequence of such triples or None)."""
+    def scalar(x):
+        return x is not None and not isinstance(x, (tuple, list)) \
+            and np.ndim(x) == 0
+    return (len(b) == 3 and b[0] is not None
+            and not isinstance(b[0], (tuple, list))
+            and scalar(b[1]) and scalar(b[2]))
 
 
 def _bloom_shape(blooms) -> Optional[tuple]:
@@ -1012,8 +1029,9 @@ def set_cache_capacity(n: int) -> int:
 def _shard_wrap(fn, nshards: int, bshape, pshape=None):
     """Wrap a batched runner in ``shard_map`` over ``nshards`` local
     devices on the (leading) batch axis. Trace arrays shard; a shared
-    Bloom filter replicates; stacked per-trace filters shard; stacked
-    policy tables/costs (the runtime policy axis) shard. Inside
+    Bloom filter replicates; stacked per-trace filters and the per-lane
+    filter mask shard; stacked policy tables/costs (the runtime policy
+    axis) shard. Inside
     each shard the wrapped fn sees a ``batch/nshards`` slice and vmaps
     over it exactly as in the unsharded path, so results concatenate to
     the bit-identical full batch. The body is a pure per-shard vmap
@@ -1027,7 +1045,8 @@ def _shard_wrap(fn, nshards: int, bshape, pshape=None):
     if bshape is None:
         in_specs = (spec,) * 5
     else:
-        in_specs = (spec,) * 5 + (spec if bshape[0] == "stacked" else P(),)
+        in_specs = (spec,) * 5 + (spec if bshape[0] == "stacked" else P(),
+                                  spec)
     if pshape is not None:
         in_specs = in_specs + (spec, spec)
     return jax_compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
@@ -1105,10 +1124,11 @@ def _batched_fn(key: tuple, ref: bool = False):
 
 def _build_runner(key: tuple, ref: bool, nshards: int) -> "_CachedRunner":
     """Construct the (lazily-compiled) runner for one cache key.
-    Argument order after the five trace arrays: the Bloom words (when
-    the key has a bloom shape), then the stacked policy tables + cost
-    pairs (when it has a policy shape) — tables/costs always ride the
-    batch axis (axis 0), one program per batch row."""
+    Argument order after the five trace arrays: the Bloom words and the
+    per-lane int32 filter mask (when the key has a bloom shape), then
+    the stacked policy tables + cost pairs (when it has a policy shape)
+    — mask, tables and costs always ride the batch axis (axis 0), one
+    entry per batch row."""
     _, slots, batch, sys, mode, bshape, pshape = key
     core = _run_core_ref if ref else _run_core
     extra = {} if ref else {"slots": slots}
@@ -1117,18 +1137,19 @@ def _build_runner(key: tuple, ref: bool, nshards: int) -> "_CachedRunner":
     if has_bloom:
         stacked, _, bk, bm = bshape
         words_axis = 0 if stacked == "stacked" else None
-    axes = (0,) * 5 + ((words_axis,) if has_bloom else ()) \
+    axes = (0,) * 5 + ((words_axis, 0) if has_bloom else ()) \
         + ((0, 0) if has_pol else ())
 
     def one(k, b, r, d, dp, *rest):
         i = 0
-        bloom_args = (None, 0, 1)
+        bloom_args, on = (None, 0, 1), {}
         if has_bloom:
-            bloom_args = (rest[0], bk, bm)
-            i = 1
+            bloom_args, on = (rest[0], bk, bm), {"bloom_on": rest[1]}
+            i = 2
         pol = ({"policy_table": rest[i], "policy_cost": rest[i + 1]}
                if has_pol else {})
-        return core(k, b, r, d, dp, sys, mode, *bloom_args, **extra, **pol)
+        return core(k, b, r, d, dp, sys, mode, *bloom_args, **extra, **on,
+                    **pol)
 
     def fn(*args):
         return jax.vmap(one, in_axes=axes)(*args)
@@ -1145,7 +1166,7 @@ def _build_runner(key: tuple, ref: bool, nshards: int) -> "_CachedRunner":
     avals = [((bb, bucket), jnp.int32)] * 5
     if bshape is not None:
         wshape = (bshape[1],) if bshape[0] == "shared" else (bb, bshape[1])
-        avals = avals + [(wshape, jnp.uint32)]
+        avals = avals + [(wshape, jnp.uint32), ((bb,), jnp.int32)]
     if has_pol:
         avals = avals + [((bb, pshape[1] + 1, 4), jnp.int32),
                          ((bb, 2), jnp.int32)]
@@ -1170,29 +1191,39 @@ def _finalize(out_row: dict, padded: Trace, sys: SystemConfig,
 
 def _normalize_blooms(blooms, n: int):
     """blooms: None | one (words, k, m_bits) filter (any sequence type)
-    | a per-trace sequence of identically-shaped filter triples. ->
-    None | shared tuple | list of tuples (no mixed None: group
-    upstream). Shared-vs-per-trace is decided by content, not container
-    type, so a list-typed single filter still broadcasts."""
+    | a per-trace sequence of identically-shaped filter triples or None
+    (no filter for that trace). -> ``(blooms, on)``: blooms None |
+    shared tuple | list of tuples, and ``on`` None (no filter) or the
+    per-trace filter mask (a list of bools). Shared-vs-per-trace is
+    decided by content, not container type, so a list-typed single
+    filter still broadcasts; unfiltered entries of a list whose filters
+    are all one object ride that object as a shared filter, and those
+    of a list of distinct filters borrow the first one's words (their
+    mask is off either way)."""
     if blooms is None:
-        return None
+        return None, None
     blooms = list(blooms)
     if _is_bloom_triple(blooms):
-        return tuple(blooms)
-    blooms = [tuple(b) for b in blooms]
+        return tuple(blooms), [True] * n
     # real exceptions, not asserts: these guard public entry points
     # (run_many / run_stream_many / Campaign) and must survive python -O
     if len(blooms) != n:
         raise ValueError(
             f"per-trace blooms ({len(blooms)}) must match len(traces) ({n})")
-    b0 = blooms[0]
-    if not all(_is_bloom_triple(b) and b[1] == b0[1] and b[2] == b0[2]
-               and np.asarray(b[0]).shape == np.asarray(b0[0]).shape
-               for b in blooms):
+    on = [b is not None for b in blooms]
+    if not any(on):
+        return None, None
+    first = blooms[on.index(True)]
+    b0 = tuple(first)
+    if not all(b is None or (_is_bloom_triple(b) and b[1] == b0[1]
+                             and b[2] == b0[2] and np.asarray(b[0]).shape
+                             == np.asarray(b0[0]).shape) for b in blooms):
         raise ValueError(
-            "per-trace blooms must share (words-shape, k, m_bits); use "
-            "Campaign to mix bloom/no-bloom points in one grid")
-    return blooms
+            "per-trace blooms must share (words-shape, k, m_bits); "
+            "Campaign groups points of different filter shapes apart")
+    if not all(on) and all(b is first for b in blooms if b is not None):
+        return b0, on
+    return [b0 if b is None else tuple(b) for b in blooms], on
 
 
 def check_mode(mode: str) -> str:
@@ -1280,7 +1311,7 @@ def prepare_tasks(traces: Sequence[Trace], sys: SystemConfig,
         traces = list(traces)
         n = len(traces)
         modes = _check_modes([mode] * n if isinstance(mode, str) else mode, n)
-        blooms = _normalize_blooms(blooms, n)
+        blooms, on = _normalize_blooms(blooms, n)
         pol = _normalize_policies(policies, policy_costs, sys, n)
 
         groups: dict = {}  # (bucket, normalized mode, table bucket) -> [idx]
@@ -1308,14 +1339,19 @@ def prepare_tasks(traces: Sequence[Trace], sys: SystemConfig,
                            for f in ("kind", "bank", "row", "delta", "dep")]
                 if blooms is None:
                     args = tuple(stacked)
-                elif isinstance(blooms, tuple):
-                    args = (*stacked, jnp.asarray(blooms[0]))
                 else:
-                    words = np.stack([np.asarray(blooms[i][0]) for i in idxs])
-                    if bb > len(idxs):
-                        words = np.concatenate(
-                            [words, np.repeat(words[:1], bb - len(idxs), axis=0)])
-                    args = (*stacked, jnp.asarray(words))
+                    if isinstance(blooms, tuple):
+                        words = blooms[0]
+                    else:
+                        words = np.stack([np.asarray(blooms[i][0])
+                                          for i in idxs])
+                        if bb > len(idxs):
+                            words = np.concatenate([words, np.repeat(
+                                words[:1], bb - len(idxs), axis=0)])
+                    # filler rows: mask off
+                    mask = np.zeros(bb, np.int32)
+                    mask[:len(idxs)] = [on[i] for i in idxs]
+                    args = (*stacked, jnp.asarray(words), jnp.asarray(mask))
                 if lb is not None:
                     tables = np.stack(
                         [smcprog.pack_program(pol[0][i], lb) for i in idxs])
@@ -1344,6 +1380,8 @@ def prepare_tasks(traces: Sequence[Trace], sys: SystemConfig,
                 cost=(slots or 2 * bucket + 4) * bb,
                 counts={"slots": slots or 2 * bucket + 4, "lanes": bb,
                         "requests": sum(reals),
+                        "masked_requests": 0 if on is None else sum(
+                            r for r, i in zip(reals, idxs) if not on[i]),
                         "shards": max(_shard_count(bb), 1)},
                 trace_ctx=tctx))
     return tasks
@@ -1393,7 +1431,9 @@ def run_many(traces: Sequence[Trace], sys: SystemConfig,
     ``mode`` is one of 'ts' | 'nots' | 'reference', or a per-trace
     sequence of them. ``blooms`` is None, one shared ``(words, k,
     m_bits)`` tuple, or a per-trace list of identically-shaped tuples
-    (stacked and vmapped alongside the traces).
+    (stacked and vmapped alongside the traces) in which None marks a
+    trace run without the filter: it rides the same dispatch with its
+    lane's filter mask off (see :func:`_normalize_blooms`).
 
     Traces are grouped by ``(length-bucket, mode)``; each group pads to
     its bucket, pads the batch axis to a power of two with all-NOP
@@ -1895,7 +1935,11 @@ def prepare_stream_tasks(streams: Sequence, sys: SystemConfig,
         streams = list(streams)
         n = len(streams)
         modes = _check_modes([mode] * n if isinstance(mode, str) else mode, n)
-        blooms = _normalize_blooms(blooms, n)
+        blooms, on = _normalize_blooms(blooms, n)
+        if on is not None and not all(on):
+            raise ValueError(
+                "streams in one run_stream_many call either all carry a "
+                "filter or none does (the window runner has no lane mask)")
         pol = _normalize_policies(policies, policy_costs, sys, n)
         H = stream_halo(sys, dep_max)
         if not isinstance(chunk, (int, np.integer)) or isinstance(chunk, bool) \
@@ -2035,7 +2079,8 @@ def prepare_stream_tasks(streams: Sequence, sys: SystemConfig,
                 label=f"stream:c{chunk}x{len(idxs)}:{gmode}{ptag}",
                 cost=SL * bb, trace_ctx=tctx,
                 # the window runner is never shard_mapped: one device
-                counts={"slots": SL, "lanes": bb, "shards": 1}))
+                counts={"slots": SL, "lanes": bb, "masked_requests": 0,
+                        "shards": 1}))
     return tasks
 
 

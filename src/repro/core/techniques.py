@@ -325,8 +325,9 @@ class TRCDReduction:
 
     def evaluate_traces(self, trs: Sequence, mode_ts: str = "ts") -> List[dict]:
         """Batched base-vs-reduced sweep: :meth:`campaign` in one run
-        (one compile per (bucket, bloom-presence) group). Returns
-        per-trace dicts in input order."""
+        (one compile and one dispatch per length bucket: the base arm
+        rides the reduced arm's dispatch with its filter mask off).
+        Returns per-trace dicts in input order."""
         arms = {(r["i"], r["arm"]): int(r["exec_cycles"])
                 for r in self.campaign(trs, mode_ts).run()}
         return [{

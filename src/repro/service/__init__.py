@@ -2,7 +2,8 @@
 
 One warm emulator engine (in-memory executable LRU + optional
 persistent XLA cache) serves many concurrent sweep clients. Submitted
-grid points are bucketed by their campaign ``group_key``; compatible
+grid points are bucketed by their campaign ``coalesce_key`` and split
+into groups by ``campaign.plan_groups``; compatible
 points FROM DIFFERENT CLIENTS coalesce into shared batched dispatches
 on the overlapped executor, and results demultiplex back to per-client
 futures bit-identically to a direct ``Campaign.run`` of the same

@@ -17,14 +17,17 @@ Architecture (one server == one process-wide warm engine):
   contention (full buckets slicing at ``max_batch``, bounded in-flight
   dispatches) a weight-2 client therefore lands ~2x the points per
   dispatch slice of a weight-1 client, and no client starves.
-* **Coalescing** — buckets key on the campaign ``group_key`` (length
-  bucket, SystemConfig — policy + faults ride it — mode, bloom shape),
-  so points from DIFFERENT clients that a ``Campaign`` would batch
-  together share one dispatch here too. A bucket flushes when it
-  reaches ``max_batch`` or its oldest point has waited
-  ``coalesce_window_s`` (the window is what lets a second client's
+* **Coalescing** — buckets key on the campaign ``coalesce_key`` (length
+  bucket, SystemConfig — policy + faults ride it — mode, policy table
+  bucket; not whether the point carries a weak-row filter), and a
+  flushed slice splits into dispatches by ``campaign.plan_groups``, the
+  rule ``Campaign.run`` groups by: filtered and unfiltered points of
+  one filter shape share a dispatch. So points from DIFFERENT clients
+  that a ``Campaign`` would batch together share one dispatch here too.
+  A bucket flushes when it reaches ``max_batch`` or its oldest point has
+  waited ``coalesce_window_s`` (the window is what lets a second client's
   burst join the first's dispatch; both the single- and multi-client
-  paths pay it). Flushed buckets become executor tasks via the same
+  paths pay it). Flushed groups become executor tasks via the same
   ``emulator.prepare_tasks`` path ``Campaign.run`` uses, so results are
   bit-identical to a direct campaign over the same points — slot
   budgets and batch padding differ by composition, which the engine's
@@ -374,7 +377,7 @@ class SweepServer:
             c = min(eligible, key=lambda cl: (cl.vtime, cl.name))
             job = c.queue.popleft()
             c.vtime += 1.0 / c.weight
-            key = job.point.group_key()
+            key = job.point.coalesce_key()
             b = self._buckets.get(key)
             if b is None:
                 self._buckets[key] = _Bucket(jobs=[job], t_open=now)
@@ -405,7 +408,9 @@ class SweepServer:
                 next_dl = 0.0 if next_dl is None else min(next_dl, 0.0)
             else:
                 del self._buckets[key]
-            flushes.append((key, slice_))
+            groups = _campaign.plan_groups([j.point for j in slice_])
+            flushes += [(gkey, [slice_[i] for i in idxs])
+                        for gkey, idxs in groups.items()]
         return flushes, next_dl
 
     def _loop(self) -> None:
@@ -458,10 +463,7 @@ class SweepServer:
                         disp.loaded = True
                         self._finish(disp)
                         return
-            blooms = None
-            if p0.bloom is not None:
-                same = all(p.bloom is p0.bloom for p in pts)
-                blooms = p0.bloom if same else [p.bloom for p in pts]
+            blooms = _campaign.group_blooms(pts)
             # runtime policy axis: policy points group apart from
             # staged/legacy ones (their group_key carries a policy
             # shape element), so a whole dispatch rides the axis

@@ -5,8 +5,8 @@ import pytest
 
 from repro.core import emulator
 from repro.core.bloom import BloomFilter
-from repro.core.campaign import Campaign
-from repro.core.emulator import Trace, run, run_many
+from repro.core.campaign import Campaign, plan_groups
+from repro.core.emulator import Trace, run, run_many, run_ref_many
 from repro.core.timescale import JETSON_NANO
 
 
@@ -131,8 +131,9 @@ class TestCompileCache:
             c.add(tr, JETSON_NANO, mode="ts")
             c.add(tr, JETSON_NANO, mode="ts", bloom=bloom)
             c.add(tr, JETSON_NANO, mode="nots")
-        # one group per (bucket, sys, mode, bloom-shape)
-        assert c.n_groups() == 3
+        # one group per (bucket, sys, mode): the unfiltered ts points ride
+        # the filtered ones' dispatch with their filter mask off
+        assert c.n_groups() == 2
 
     def test_mixed_ts_reference_share_group(self):
         """'reference' compiles to the 'ts' program, so mixing the two
@@ -331,3 +332,105 @@ class TestApiEdges:
         for tr, bf, r in zip(trs, blooms, stacked):
             single = run(tr, JETSON_NANO, "ts", bloom=bf)
             assert int(single["exec_cycles"]) == int(r["exec_cycles"])
+
+
+FIELDS = ("exec_cycles", "row_hits", "served", "dram_ticks",
+          "smc_fpga_cycles", "t_resp", "t_issue")
+
+
+def assert_same(a, b):
+    for f in FIELDS:
+        np.testing.assert_array_equal(np.asarray(a[f]), np.asarray(b[f]), f)
+
+
+class TestFilterMask:
+    """Points that differ only in whether the weak-row filter applies
+    share one group and one dispatch; the unfiltered ones ride it with
+    their lane's filter mask off, bit-identical to running apart."""
+
+    @staticmethod
+    def arms(trs, mode, filters):
+        bloom = small_bloom()
+        c = Campaign()
+        for i, tr in enumerate(trs):
+            # "stacked": a distinct filter of one shape per point
+            b = bloom if filters == "shared" else small_bloom(seed=i)
+            c.add(tr, JETSON_NANO, mode=mode, i=i, arm="base")
+            c.add(tr, JETSON_NANO, mode=mode, bloom=b, i=i, arm="reduced")
+        return c
+
+    @pytest.mark.parametrize("filters", ["shared", "stacked"])
+    @pytest.mark.parametrize("mode", ["ts", "nots"])
+    def test_mixed_arms_match_separate_groups_and_reference(self, mode,
+                                                           filters):
+        trs = mixed_traces(3)
+        c = self.arms(trs, mode, filters)
+        assert c.n_groups() == 1
+        got = c.run()
+        blooms = [p.bloom for p in c.points[1::2]]
+        base, reduced = run_many(trs, JETSON_NANO, mode), \
+            run_many(trs, JETSON_NANO, mode, blooms=blooms)
+        ref_base, ref_reduced = run_ref_many(trs, JETSON_NANO, mode), \
+            run_ref_many(trs, JETSON_NANO, mode, blooms=blooms)
+        for i in range(len(trs)):
+            assert_same(got[2 * i], base[i])
+            assert_same(got[2 * i], ref_base[i])
+            assert_same(got[2 * i + 1], reduced[i])
+            assert_same(got[2 * i + 1], ref_reduced[i])
+        # in ts the filter changes the answers, so a mask that leaked
+        # either way would show (in nots the slow SMC hides DRAM timing
+        # and the arms coincide)
+        assert (mode == "nots") != any(
+            not np.array_equal(got[2 * i]["t_resp"], got[2 * i + 1]["t_resp"])
+            for i in range(len(trs)))
+
+    @pytest.mark.parametrize("on", [(1, 0, 1), (0, 1, 0)])
+    def test_run_many_takes_unfiltered_entries(self, on):
+        trs = mixed_traces(3)
+        bloom = small_bloom()
+        blooms = [bloom if o else None for o in on]
+        words, mask = emulator._normalize_blooms(blooms, 3)
+        assert words == tuple(bloom) and mask == [bool(o) for o in on]
+        for tr, bl, r in zip(trs, blooms,
+                             run_many(trs, JETSON_NANO, "ts", blooms=blooms)):
+            assert_same(r, run(tr, JETSON_NANO, "ts", bloom=bl))
+        assert emulator._normalize_blooms([None, None], 2) == (None, None)
+
+    def test_two_filter_shapes_keep_the_unfiltered_group(self):
+        trs = mixed_traces(2)
+        blooms = (None, small_bloom(), small_bloom(m_bits=1 << 15))
+        c = Campaign()
+        for tr in trs:
+            for b in blooms:
+                c.add(tr, JETSON_NANO, bloom=b)
+        groups = plan_groups(c.points)
+        assert sorted(groups.values()) == [[0, 3], [1, 4], [2, 5]]
+        assert emulator.group_key(trs[0].n, JETSON_NANO, "ts", None) \
+            in groups
+        for p, r in zip(c.points, c.run()):
+            assert_same(r, run(p.trace, JETSON_NANO, "ts", bloom=p.bloom))
+
+    def test_unfiltered_only_campaign_compiles_as_before(self):
+        """No filtered point: the group compiles the no-filter program
+        under the key it always had (no words, no mask operand), one
+        miss for one group."""
+        import dataclasses
+        sysc = dataclasses.replace(JETSON_NANO, window=3)  # a fresh key
+        trs = mixed_traces(3)
+        c = Campaign().extend(trs, sysc)
+        assert list(plan_groups(c.points)) == [
+            emulator.group_key(trs[0].n, sysc, "ts", None)]
+        before = emulator.cache_stats()["misses"]
+        c.run()
+        assert emulator.cache_stats()["misses"] - before == 1
+        slots = emulator.slot_budget(128, max(t.n_real for t in trs))
+        key = emulator.compile_key(128, 3, sysc, "ts", None, slots)
+        runner = emulator._COMPILE_CACHE[
+            ("fast", emulator._shard_count(4), key)]
+        assert len(runner.avals) == 5   # the five trace arrays alone
+
+    def test_streams_do_not_mix_filter_arms(self):
+        trs = mixed_traces(2)
+        with pytest.raises(ValueError, match="all carry a filter"):
+            emulator.run_stream_many(trs, JETSON_NANO, "ts",
+                                     blooms=[small_bloom(), None])

@@ -83,3 +83,15 @@ def test_policy_axis_runner_compiles(one_chip):
         bucket, 256, emulator._policy_rt_sys(JETSON_NANO), "ts", None,
         emulator.slot_budget(bucket, bucket), smcprog.table_bucket(8))
     _compile_fits(emulator._build_runner(key, False, 0), one_chip)
+
+
+def test_filtered_runner_compiles(one_chip):
+    """The tRCD sweep's dispatch of both arms: 32 lanes of 4096 with a
+    shared 1 Mibit filter and the per-lane filter mask."""
+    bucket = 4096
+    bloom = (jnp.zeros((1 << 20) // 32, jnp.uint32), 4, 1 << 20)
+    key = emulator.compile_key(bucket, 32, JETSON_NANO, "ts", bloom,
+                               emulator.slot_budget(bucket, bucket))
+    runner = emulator._build_runner(key, False, 0)
+    assert runner.avals[-1] == ((32,), jnp.int32)   # the mask
+    _compile_fits(runner, one_chip)

@@ -405,21 +405,26 @@ class TestSharding:
     def test_forced_single_device_shard_map_bit_identical(self):
         """The shard_map code path itself (1-device mesh) must be
         bit-identical to the plain vmap path — the single-device half
-        of the sharding contract."""
+        of the sharding contract — with no filter, a shared one, and
+        filtered and unfiltered traces in one dispatch (the filter mask
+        sharded with the traces, beside shared or stacked words)."""
         rng = np.random.RandomState(5)
         trs = [mk_trace(rng, 40) for _ in range(4)]
         bloom = small_bloom(5)
+        grids = (None, bloom, [bloom, None, bloom, None],
+                 [None, small_bloom(1), small_bloom(2), None])
         old = emulator.set_sharding("force")
         try:
-            a = run_many(trs, JETSON_NANO, "ts")
-            ab = run_many(trs, JETSON_NANO, "ts", blooms=bloom)
+            forced = [run_many(trs, JETSON_NANO, "ts", blooms=g)
+                      for g in grids]
         finally:
             emulator.set_sharding(old)
-        b = run_many(trs, JETSON_NANO, "ts")
-        bb = run_many(trs, JETSON_NANO, "ts", blooms=bloom)
-        for x, y in zip(a + ab, b + bb):
-            assert int(x["exec_cycles"]) == int(y["exec_cycles"])
-            np.testing.assert_array_equal(x["t_resp"], y["t_resp"])
+        plain = [run_many(trs, JETSON_NANO, "ts", blooms=g) for g in grids]
+        for a, b in zip(forced, plain):
+            for x, y in zip(a, b):
+                assert int(x["exec_cycles"]) == int(y["exec_cycles"])
+                np.testing.assert_array_equal(x["t_resp"], y["t_resp"])
+                np.testing.assert_array_equal(x["t_issue"], y["t_issue"])
 
     def test_set_sharding_validates(self):
         with pytest.raises(ValueError, match="sharding mode"):

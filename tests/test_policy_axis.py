@@ -162,6 +162,28 @@ class TestCampaignPolicyAxis:
             assert a["policy"] == b["policy"]
             assert_same(a, b, tr.n, a["policy"])
 
+    def test_filter_arm_rides_the_policy_axis(self):
+        """A policy grid run with and without the weak-row filter is one
+        group and one dispatch; every row is bit-identical to its staged
+        program run with or without the filter."""
+        from repro.core.bloom import BloomFilter
+        tr = mk_trace(9)
+        progs = program_pool(n_random=2)
+        rng = np.random.RandomState(0)
+        bf = BloomFilter.build(rng.randint(0, 1 << 19, 150).astype(np.uint32),
+                               m_bits=1 << 14, k=3)
+        c = Campaign()
+        for b in (None, (bf.bits, bf.k, bf.m_bits)):
+            c.points += [Point(tr, JETSON_NANO, "ts", b, {"policy": p.name},
+                               policy=p,
+                               policy_cost=JETSON_NANO.smc_cycles_per_decision)
+                         for p in progs]
+        assert c.n_groups() == 1
+        for pt, r in zip(c.points, c.run(serial=True)):
+            staged = run(tr, dataclasses.replace(JETSON_NANO, policy=pt.policy),
+                         "ts", bloom=pt.bloom)
+            assert_same(staged, r, tr.n, pt.meta["policy"])
+
     def test_mixed_buckets_raise_naming_program(self):
         b = smcprog.PolicyBuilder()
         v = b.score_age()

@@ -14,7 +14,7 @@ import pytest
 
 from repro.core import emulator, executor
 from repro.core.bloom import BloomFilter
-from repro.core.campaign import Campaign, Point
+from repro.core.campaign import Campaign, Point, plan_groups
 from repro.core.emulator import Trace
 from repro.core.faults import FaultModel
 from repro.core.smcprog import frfcfs_program
@@ -111,6 +111,25 @@ class TestBitIdentity:
             assert_same_record(got[i], r)
         assert st["dispatches"]["points"] == len(pts)
         assert st["rejected"] == 0
+
+    def test_filter_arms_share_dispatches_like_campaign(self):
+        """Base and filtered points from two clients coalesce by the
+        campaign's rule: one dispatch per bucket, each record
+        bit-identical to a serial Campaign over the same points."""
+        trs = mk_traces(3)   # buckets 64, 128, 128
+        bloom = small_bloom()
+        pts = [Point(tr, JETSON_NANO, "ts", b, {"idx": 2 * i + (b is not None)})
+               for i, tr in enumerate(trs) for b in (None, bloom)]
+        ref = serial_reference(pts)
+        with SweepServer(coalesce_window_s=0.25) as srv:
+            clis = [SweepClient(server=srv, name=f"c{k}") for k in range(2)]
+            for k, cli in enumerate(clis):
+                cli.submit_points(pts[k::2])
+            got = {r["idx"]: r for cli in clis for r in cli.collect()}
+            st = srv.stats()
+        assert st["dispatches"]["count"] == len(plan_groups(pts)) == 2
+        for i, r in enumerate(ref):
+            assert_same_record(got[i], r)
 
     def test_coalesces_across_clients(self):
         """Same-group points from different clients share dispatches:

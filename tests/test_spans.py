@@ -192,3 +192,32 @@ def test_concurrent_spans_lose_nothing(recording, monkeypatch):
     assert len(recs) == cap
     assert len(recs) + spans.dropped() == n_threads * n_spans
     assert len({r.id for r in recs}) == len(recs)
+
+
+@pytest.mark.parametrize("arms", ["none", "all", "mixed"])
+def test_batched_dispatches_count_masked_requests(monkeypatch, arms):
+    """Every batched dispatch counts the requests of its lanes whose
+    weak-row filter mask is off (0 where no lane is masked), and every
+    stream window counts 0. Planned only: nothing compiles."""
+    from repro.core import emulator
+    from repro.core.emulator import Trace
+    from repro.core.timescale import JETSON_NANO
+    monkeypatch.setattr(emulator._CachedRunner, "prime", lambda self: self)
+    kinds = ([0, 1, 4, 0], [1, 4, 4, 4], [0] * 40, [1, 0, 1])
+    trs = [Trace.of(kind=k, bank=np.zeros(len(k)), row=np.arange(len(k)),
+                    delta=np.ones(len(k))) for k in kinds]
+    bloom = (np.zeros(32, np.uint32), 2, 1024)
+    blooms = {"none": None, "all": bloom,
+              "mixed": [bloom, None, None, bloom]}[arms]
+    tasks = emulator.prepare_tasks(trs, JETSON_NANO, "ts", blooms,
+                                   [None] * len(trs))
+    got = sorted((t.counts["requests"], t.counts["masked_requests"])
+                 for t in tasks)
+    # buckets 32 (traces 0, 1, 3) and 64 (trace 2)
+    want = {"none": [(7, 0), (40, 0)], "all": [(7, 0), (40, 0)],
+            "mixed": [(7, 1), (40, 40)]}[arms]
+    assert got == want
+    if arms != "mixed":
+        (st,) = emulator.prepare_stream_tasks(trs, JETSON_NANO, "ts", blooms,
+                                              [None] * len(trs))
+        assert st.counts["masked_requests"] == 0
