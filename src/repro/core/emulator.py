@@ -119,7 +119,7 @@ import numpy as np
 
 from repro.core import dram, executor, faults as faultmod, smcprog, spans
 from repro.core.bloom import bloom_probe_jnp
-from repro.core.dram import NOP, WRITE
+from repro.core.dram import NOP, RC_COPY, RC_INIT, WRITE
 from repro.core.timescale import SystemConfig
 
 BIG = np.int32(2 ** 30)  # host constant: importing touches no device
@@ -1323,6 +1323,7 @@ def prepare_tasks(traces: Sequence[Trace], sys: SystemConfig,
         tasks: List[executor.GroupTask] = []
         for (bucket, gmode, lb), idxs in groups.items():
             reals = [traces[i].n_real for i in idxs]
+            kinds = np.concatenate([traces[i].kind for i in idxs])
             slots = None if ref else slot_budget(bucket, max(reals))
             gsys = sys if lb is None else _policy_rt_sys(sys)
             key = compile_key(bucket, len(idxs), gsys, gmode, blooms, slots, lb)
@@ -1379,12 +1380,23 @@ def prepare_tasks(traces: Sequence[Trace], sys: SystemConfig,
                 label=f"b{bucket}x{len(idxs)}:{gmode}{ptag}",
                 cost=(slots or 2 * bucket + 4) * bb,
                 counts={"slots": slots or 2 * bucket + 4, "lanes": bb,
-                        "requests": sum(reals),
                         "masked_requests": 0 if on is None else sum(
                             r for r, i in zip(reals, idxs) if not on[i]),
+                        **_kind_counts(kinds),
                         "shards": max(_shard_count(bb), 1)},
                 trace_ctx=tctx))
     return tasks
+
+
+def _kind_counts(kinds) -> dict:
+    """The ``emu.dispatch`` span's counts over the request kinds a
+    dispatch newly emulates: ``requests`` (non-NOP), ``write_requests``
+    (WRITE) and ``rc_requests`` (RC_COPY and RC_INIT)."""
+    kinds = np.asarray(kinds)
+    return {"requests": int(np.count_nonzero(kinds != NOP)),
+            "write_requests": int(np.count_nonzero(kinds == WRITE)),
+            "rc_requests": int(np.count_nonzero(
+                (kinds == RC_COPY) | (kinds == RC_INIT)))}
 
 
 def _execute_entry_point(tasks, serial) -> None:
@@ -2007,15 +2019,15 @@ def prepare_stream_tasks(streams: Sequence, sys: SystemConfig,
                 # final one: it ships with the freeze LIFTED (final=1) and
                 # drains the whole tail in-budget — no separate flush
                 # dispatch (SL covers a full fresh chunk plus the carried
-                # halo, the exact worst case). Each window comes with its
-                # fresh non-NOP requests, for the dispatch span's count.
+                # halo, the exact worst case). Each window comes with the
+                # counts of its fresh requests, for the dispatch span.
                 chunkers = ctx["chunkers"]
                 filler = _nop_fields(chunk)
                 k = 0
                 while not all(c.done for c in chunkers):
                     blocks = [c.next_block() for c in chunkers]
-                    fresh = sum(int(np.count_nonzero(b[0] != NOP))
-                                for b in blocks)
+                    fresh = _kind_counts(
+                        np.concatenate([b[0] for b in blocks]))
                     blocks += [filler] * (bb - len(blocks))
                     final = all(c.done for c in chunkers)
                     if final:
@@ -2029,7 +2041,8 @@ def prepare_stream_tasks(streams: Sequence, sys: SystemConfig,
                     blocks = [filler] * bb
                     yield tuple(
                         jnp.asarray(np.stack([b[i] for b in blocks]))
-                        for i in range(5)) + (jnp.int32(1),) + wargs, 0
+                        for i in range(5)) + (jnp.int32(1),) + wargs, \
+                        _kind_counts(())
 
             def consume(out, ctx, idxs=idxs):
                 kind_blk, issue_blk, resp_blk, ptr = out

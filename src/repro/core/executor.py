@@ -96,7 +96,7 @@ class StreamTask:
     single dispatch (see ``repro.core.emulator.prepare_stream_tasks``).
 
     ``pack`` builds the initial carried state plus a host context;
-    ``windows(ctx)`` yields one ``(args, requests)`` pair per trace
+    ``windows(ctx)`` yields one ``(args, counts)`` pair per trace
     window (the last one freeze-lifted to drain the tail in place);
     ``fn(state, *args)`` is the resolved window
     executable returning ``(new_state, emitted)``; ``consume`` receives
@@ -115,12 +115,13 @@ class StreamTask:
     caller's thread; prefetch changes wall-clock interleaving only,
     never the window sequence.
 
-    ``requests`` are the requests a window brings fresh, which its
-    ``emu.dispatch`` span counts beside ``counts``. ``trace_ctx`` is as
+    A window's own ``counts`` (``requests``, ``write_requests``,
+    ``rc_requests``: the requests it brings fresh) are its
+    ``emu.dispatch`` span's counts beside the task's. ``trace_ctx`` is as
     on :class:`GroupTask`; the prefetch thread re-enters it too."""
     fn: Callable[..., Any]
     pack: Callable[[], Tuple[Any, Any]]
-    windows: Callable[[Any], Any]  # ctx -> iterable of (arg tuple, requests)
+    windows: Callable[[Any], Any]  # ctx -> iterable of (arg tuple, counts)
     consume: Callable[[tuple, Any], None]
     finalize: Callable[[Any, Any], None]
     label: str = ""
@@ -198,7 +199,7 @@ class StreamTask:
                 if isinstance(win, BaseException):
                     raise win
                 args, fresh = win
-                with spans.span("emu.dispatch", requests=fresh, **self.counts):
+                with spans.span("emu.dispatch", **fresh, **self.counts):
                     state, out = self.fn(state, *args)   # device: one window
                 with spans.span("emu.wait"):
                     out = tuple(np.asarray(o) for o in out)

@@ -19,7 +19,7 @@ with :func:`attach`, so one top-level call keeps one id across
 threads.
 
 Span names used by the emulator (``repro.core.emulator``,
-``executor``, ``campaign``):
+``executor``, ``campaign``) and its techniques:
 
 * ``emu.call`` — one top-level call (``run_many``, ``run_stream_many``,
   ``Campaign.run``); counts ``requests``, the non-NOP requests of the
@@ -33,12 +33,17 @@ Span names used by the emulator (``repro.core.emulator``,
 * ``emu.prefetch_wait`` — the stream loop waiting for its next window.
 * ``emu.dispatch`` — enqueueing one executable call; counts ``slots``
   (scan length), ``lanes`` (padded batch), ``requests`` (non-NOP
-  requests this dispatch newly emulates) and ``shards`` (devices the
-  batch is split over).
+  requests this dispatch newly emulates), ``masked_requests`` (those in
+  lanes whose weak-row filter mask is off), ``write_requests`` and
+  ``rc_requests`` (the WRITE, and the RC_COPY and RC_INIT, among
+  ``requests``) and ``shards`` (devices the batch is split over).
 * ``emu.wait`` — gathering a dispatch's outputs (blocks on the device).
 * ``emu.consume`` — folding one stream window's outputs into the
   per-stream accumulators.
 * ``emu.finalize`` — turning a group's outputs into result records.
+* ``tech.traces`` — ``RowClone.campaign`` building its grid's traces
+  and profiling clonability (``repro.core.techniques``); counts
+  ``points`` and ``fallback_rows``. It encloses no other span.
 """
 from __future__ import annotations
 

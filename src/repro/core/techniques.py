@@ -13,22 +13,23 @@ intensities under the fault-injection model (``repro.core.faults``),
 trading bit-error rate against emulated slowdown.
 
 Evaluation goes through the batched campaign path
-(``emulator.run_many`` / ``campaign.Campaign``): ``evaluate_batch`` /
-``evaluate_traces`` sweep many sizes or workloads with one compile and
-one dispatch per compile-key group; the single-point ``evaluate`` /
-``evaluate_trace`` are thin wrappers over a batch of one pair.
+(``emulator.run_many`` / ``campaign.Campaign``): ``RowClone.campaign``
+and ``TRCDReduction.campaign`` build a study's whole grid as one
+Campaign, with one compile and one dispatch per compile-key group;
+``evaluate_batch`` / ``evaluate_traces`` read their records, and the
+single-point ``evaluate`` / ``evaluate_trace`` are batches of one.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+import itertools
+from typing import Dict, List, Mapping, NamedTuple, Optional, Sequence
 
 import numpy as np
 
-from repro.core import smcprog, traces
+from repro.core import smcprog, spans, traces
 from repro.core.campaign import Campaign, Point
 from repro.core.bloom import BloomFilter
-from repro.core.dram import Geometry
 from repro.core.faults import FaultModel
 from repro.core.profiling import DeviceModel
 from repro.core.smcprog import PolicyProgram
@@ -46,6 +47,21 @@ class RowCloneResult:
     speedup_vs_cpu: float = 0.0
 
 
+class Platform(NamedTuple):
+    """One system of a RowClone study: its configuration, evaluation
+    mode, and the per-line cost of the *modeled* CPU's copy loop in
+    processor cycles (a 3-wide OoO core with 64B NEON moves retires far
+    fewer cycles a line than a 50 MHz single-issue rv64; None keeps the
+    trace generator's default)."""
+    sys: SystemConfig
+    mode: str = "ts"
+    cpu_line_delta: Optional[int] = None
+
+
+_CLONE_WORKLOADS = {"copy": traces.copy_workload,
+                    "init": traces.init_workload}
+
+
 class RowClone:
     """In-DRAM bulk copy/initialization (Sec. 7)."""
 
@@ -54,52 +70,84 @@ class RowClone:
         self.geo = sys.geometry
         self.device = device or DeviceModel(self.geo)
 
+    def campaign(self, sizes: Sequence[int],
+                 workloads: Sequence[str] = ("copy", "init"),
+                 settings: Sequence[str] = ("noflush", "clflush"),
+                 systems: Optional[Mapping[str, Platform]] = None,
+                 alloc_base_rows: Optional[Mapping[str, int]] = None,
+                 ) -> Campaign:
+        """The Sec. 7 grid as one :class:`Campaign`: every (size,
+        workload, setting, system, arm) point, in that order, arm
+        ``cpu`` (load/store per line) against ``rowclone`` (in-DRAM ops
+        with profiled CPU fallback). ``systems`` maps names to
+        :class:`Platform` s (default: this study's system, mode ``ts``);
+        ``alloc_base_rows`` maps a workload to its allocation's base row
+        (default: the generator's), so callers can draw fresh
+        allocations. Records carry ``j`` (position in ``sizes``),
+        ``n_bytes``, ``workload``, ``setting``, ``arm``, ``system`` and
+        ``fallback_rows``. Traces and clonability profiling are built
+        here, in the ``tech.traces`` span."""
+        systems = {"sys": Platform(self.sys)} if systems is None \
+            else dict(systems)
+        for name, pf in systems.items():
+            if pf.sys.geometry != self.geo:  # traces are laid out on self.geo
+                raise ValueError(f"system {name!r} has another geometry "
+                                 f"than the study's {self.geo}")
+        alloc = alloc_base_rows or {}
+        c = Campaign()
+        with spans.span("tech.traces") as sp:
+            for (j, nb), wl, setting, (name, pf), arm in itertools.product(
+                    enumerate(sizes), workloads, settings, systems.items(),
+                    ("cpu", "rowclone")):
+                kw = {k: v for k, v in (("alloc_base_row", alloc.get(wl)),
+                                        ("cpu_line_delta", pf.cpu_line_delta))
+                      if v is not None}
+                tr, meta = _CLONE_WORKLOADS[wl](nb, self.geo, arm, self.device,
+                                                setting, **kw)
+                c.add(tr, pf.sys, mode=pf.mode, j=j, n_bytes=nb, workload=wl,
+                      setting=setting, arm=arm, system=name,
+                      fallback_rows=meta["fallback_rows"])
+            if sp:
+                sp.add(points=len(c), fallback_rows=sum(
+                    p.meta["fallback_rows"] for p in c.points))
+        return c
+
+    @staticmethod
+    def results(records: Sequence[dict]) -> Dict[tuple, dict]:
+        """:meth:`campaign` records by ``(j, workload, setting,
+        system)``: ``{'cpu': RowCloneResult, 'rowclone':
+        RowCloneResult}``, the RowClone arm's ``speedup_vs_cpu`` set."""
+        out: Dict[tuple, dict] = {}
+        for r in records:
+            key = (r["j"], r["workload"], r["setting"], r["system"])
+            out.setdefault(key, {})[r["arm"]] = RowCloneResult(
+                mode=r["arm"], setting=r["setting"], n_bytes=r["n_bytes"],
+                exec_cycles=int(r["exec_cycles"]),
+                exec_seconds=r["exec_seconds"],
+                fallback_rows=r["fallback_rows"])
+        for d in out.values():
+            d["rowclone"].speedup_vs_cpu = \
+                d["cpu"].exec_cycles / max(d["rowclone"].exec_cycles, 1)
+        return out
+
     def evaluate(self, n_bytes: int, workload: str = "copy",
                  setting: str = "noflush", mode_ts: str = "ts",
                  cpu_line_delta: int = None):
-        """Returns {'cpu': RowCloneResult, 'rowclone': RowCloneResult}.
-
-        cpu_line_delta models the per-line instruction cost of the
-        *modeled* CPU's copy loop (a 3-wide OoO core with 64B NEON moves
-        retires far fewer cycles/line than a 50 MHz single-issue rv64)."""
+        """Returns {'cpu': RowCloneResult, 'rowclone': RowCloneResult}."""
         return self.evaluate_batch([n_bytes], workload, setting, mode_ts,
                                    cpu_line_delta)[0]
 
     def evaluate_batch(self, sizes: Sequence[int], workload: str = "copy",
                        setting: str = "noflush", mode_ts: str = "ts",
                        cpu_line_delta: int = None) -> List[dict]:
-        """Sweep ``sizes`` in one batched campaign: all (cpu, rowclone)
-        trace pairs run through a single ``run_many`` call per
-        compile-key group — one compile and one dispatch per (bucket,
-        slot-budget) group, with the short RowClone traces paying only
-        their exact slot budget rather than the CPU arm's. Returns one
-        {'cpu': ..., 'rowclone': ...} dict per size, in order."""
-        gen = traces.copy_workload if workload == "copy" else traces.init_workload
-        kw = {} if cpu_line_delta is None else {"cpu_line_delta": cpu_line_delta}
+        """Sweep ``sizes`` for one workload, setting and mode through
+        :meth:`campaign`: one {'cpu': ..., 'rowclone': ...} dict per
+        size, in order."""
         sizes = list(sizes)
-        c = Campaign()
-        fallbacks = {}
-        for j, nb in enumerate(sizes):  # positional index: duplicate sizes
-            for arm in ("cpu", "rowclone"):   # stay independent evaluations
-                tr, meta = gen(nb, self.geo, mode=arm, device=self.device,
-                               setting=setting, **kw)
-                c.add(tr, self.sys, mode=mode_ts, j=j, arm=arm)
-                fallbacks[(j, arm)] = meta["fallback_rows"]
-        recs = {(r["j"], r["arm"]): r for r in c.run()}
-        out = []
-        for j, nb in enumerate(sizes):
-            d = {}
-            for arm in ("cpu", "rowclone"):
-                r = recs[(j, arm)]
-                d[arm] = RowCloneResult(
-                    mode=arm, setting=setting, n_bytes=nb,
-                    exec_cycles=int(r["exec_cycles"]),
-                    exec_seconds=r["exec_seconds"],
-                    fallback_rows=fallbacks[(j, arm)])
-            d["rowclone"].speedup_vs_cpu = \
-                d["cpu"].exec_cycles / max(d["rowclone"].exec_cycles, 1)
-            out.append(d)
-        return out
+        res = self.results(self.campaign(
+            sizes, (workload,), (setting,),
+            {"sys": Platform(self.sys, mode_ts, cpu_line_delta)}).run())
+        return [res[(j, workload, setting, "sys")] for j in range(len(sizes))]
 
 
 class SchedulingPolicyStudy:

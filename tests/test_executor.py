@@ -200,7 +200,7 @@ class TestStreamPrefetchShutdown:
     def _task(n_windows=64, fn=None):
         def windows(ctx):
             for i in range(n_windows):
-                yield (np.full(4, i),), 0
+                yield (np.full(4, i),), {"requests": 0}
         return executor.StreamTask(
             fn=fn or (lambda state, a: (state + 1, (a,))),
             pack=lambda: (0, None), windows=windows,
@@ -233,7 +233,7 @@ class TestStreamPrefetchShutdown:
 
     def test_feeder_error_surfaces_on_consumer(self):
         def windows(ctx):
-            yield (np.zeros(1),), 0
+            yield (np.zeros(1),), {"requests": 0}
             raise ValueError("generator died")
 
         t = self._task()

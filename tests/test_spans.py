@@ -56,7 +56,8 @@ def _stream_task(ctx=None, n_windows=3):
     """Window ``i`` brings ``i`` fresh requests."""
     return executor.StreamTask(
         fn=lambda state, a: (state + 1, (a,)), pack=lambda: (0, None),
-        windows=lambda c: (((np.full(2, i),), i) for i in range(n_windows)),
+        windows=lambda c: (((np.full(2, i),), {"requests": i})
+                           for i in range(n_windows)),
         consume=lambda out, c: None, finalize=lambda state, c: None,
         label="s", counts={k: v for k, v in COUNTS.items() if k != "requests"},
         trace_ctx=ctx)
