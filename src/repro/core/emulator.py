@@ -65,7 +65,7 @@ property tests against the reference engine.
 Entry points:
 
 * :func:`run` — one trace, one config, one mode. A thin wrapper over a
-  batch of one.
+  batch of one, which runs beside a filler lane (:func:`_batch_bucket`).
 * :func:`run_many` — a batched campaign step: pads every trace to one
   length bucket, stacks them on a leading axis, and ``jax.vmap``s the
   scan over that axis (optionally over per-trace Bloom filters and a
@@ -804,13 +804,27 @@ def slot_budget(bucket: int, n_real: int) -> int:
     return 2 * rq + (bucket - rq + 3) // 4 + 4
 
 
-def _batch_bucket(b: int) -> int:
-    """Pad the batch axis to a power of two so sweeps of nearby sizes
-    share one executable (padding rows are all-NOP traces)."""
+def _pow2_ceil(b: int) -> int:
     p = 1
     while p < b:
         p *= 2
     return p
+
+
+def _batch_bucket(b: int) -> int:
+    """Pad the batch axis to a power of two, and to at least two lanes,
+    so sweeps of nearby sizes share one executable (padding rows are
+    all-NOP traces whose results are discarded).
+
+    The floor of two: with a batch axis of size 1, XLA squeezes the
+    axis and lowers the per-lane queue gathers and point scatters
+    (123 gathers, 42 scatters at two lanes) to scalar-indexed dynamic
+    slices and updates (158 and 42), run as serial loop fusions of
+    about 0.85 us each against about 0.35 us for a gather fusion. A
+    slot then costs about 113 us on a TPU v5e against about 31 us at
+    two lanes (PERF.md). A lone trace therefore runs beside one filler
+    lane, in the executable a two-trace group of the same key uses."""
+    return max(_pow2_ceil(b), 2)
 
 
 # ---------------------------------------------------------------------------
@@ -851,20 +865,33 @@ def set_sharding(mode: str) -> str:
     return old
 
 
-def _shard_count(batch: int) -> int:
+def _shard_count(batch: int, min_lanes: int = 2) -> int:
     """Number of mesh devices for a padded batch axis of ``batch``:
     0 = no shard_map wrapper; >= 1 = wrap over that many devices (1 only
     under 'force'). The padded batch is a power of two, so the largest
-    power-of-two device count that divides it is used."""
+    power-of-two device count that divides it and leaves each device
+    ``min_lanes`` lanes is used: the floor of :func:`_batch_bucket`
+    holds per device, so no device scans a 1-lane batch (113 against
+    ~31 us a slot). ``min_lanes=1`` is the rule without the floor,
+    which :func:`_floored` compares against."""
     if _SHARD_MODE == "off":
         return 0
     ndev = jax.local_device_count()
     n = 1
-    while n * 2 <= ndev and batch % (n * 2) == 0:
+    while (n * 2 <= ndev and batch % (n * 2) == 0
+           and batch // (n * 2) >= min_lanes):
         n *= 2
     if n == 1 and _SHARD_MODE != "force":
         return 0
     return n
+
+
+def _floored(b: int) -> int:
+    """The ``emu.dispatch`` count ``floored`` of a batched group of
+    ``b`` traces: 1 where the two-lane floor widened it, i.e. without
+    the floor some device would have scanned it at one lane."""
+    bb = _pow2_ceil(b)
+    return int(bb // max(_shard_count(bb, min_lanes=1), 1) == 1)
 
 
 def _norm_mode(mode: str) -> str:
@@ -1383,7 +1410,8 @@ def prepare_tasks(traces: Sequence[Trace], sys: SystemConfig,
                         "masked_requests": 0 if on is None else sum(
                             r for r, i in zip(reals, idxs) if not on[i]),
                         **_kind_counts(kinds),
-                        "shards": max(_shard_count(bb), 1)},
+                        "shards": max(_shard_count(bb), 1),
+                        "floored": _floored(len(idxs))},
                 trace_ctx=tctx))
     return tasks
 
@@ -1448,11 +1476,12 @@ def run_many(traces: Sequence[Trace], sys: SystemConfig,
     lane's filter mask off (see :func:`_normalize_blooms`).
 
     Traces are grouped by ``(length-bucket, mode)``; each group pads to
-    its bucket, pads the batch axis to a power of two with all-NOP
-    traces, computes its exact :func:`slot_budget` from the largest
-    member, and executes as ONE vmapped, jit-cached call (trace buffers
-    donated; batch axis sharded across local devices when present —
-    see :func:`set_sharding`). Multi-group calls overlap host packing
+    its bucket, pads the batch axis to a power of two of at least two
+    lanes with all-NOP traces (:func:`_batch_bucket`), computes its
+    exact :func:`slot_budget` from the largest member, and executes as
+    ONE vmapped, jit-cached call (trace buffers donated; batch axis
+    sharded across local devices when present — see
+    :func:`set_sharding`). Multi-group calls overlap host packing
     with device compute across the ``repro.core.executor`` worker pool;
     ``serial=True`` forces the in-order loop (bit-identical, for A/B).
     Returns one dict per input trace, in input order, bit-identical to
@@ -2091,9 +2120,10 @@ def prepare_stream_tasks(streams: Sequence, sys: SystemConfig,
                 finalize=finalize,
                 label=f"stream:c{chunk}x{len(idxs)}:{gmode}{ptag}",
                 cost=SL * bb, trace_ctx=tctx,
-                # the window runner is never shard_mapped: one device
+                # the window runner is never shard_mapped: one device,
+                # so only a lone stream is widened by the floor
                 counts={"slots": SL, "lanes": bb, "masked_requests": 0,
-                        "shards": 1}))
+                        "shards": 1, "floored": int(len(idxs) == 1)}))
     return tasks
 
 

@@ -36,7 +36,9 @@ Span names used by the emulator (``repro.core.emulator``,
   requests this dispatch newly emulates), ``masked_requests`` (those in
   lanes whose weak-row filter mask is off), ``write_requests`` and
   ``rc_requests`` (the WRITE, and the RC_COPY and RC_INIT, among
-  ``requests``) and ``shards`` (devices the batch is split over).
+  ``requests``), ``shards`` (devices the batch is split over) and
+  ``floored`` (1 where the two-lane floor of the batch axis widened a
+  dispatch that would otherwise have run one lane a device, else 0).
 * ``emu.wait`` — gathering a dispatch's outputs (blocks on the device).
 * ``emu.consume`` — folding one stream window's outputs into the
   per-stream accumulators.
